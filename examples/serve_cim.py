@@ -59,8 +59,9 @@ def main():
     done = eng.run()
     dt = time.monotonic() - t0
     print(f"served {len(done)} requests, {eng.generated_tokens} tokens in "
-          f"{dt:.1f}s ({eng.generated_tokens/dt:.1f} tok/s on 1 CPU core, "
-          f"Pallas interpret mode)")
+          f"{dt:.1f}s ({eng.generated_tokens/dt:.1f} tok/s on "
+          f"{jax.devices()[0].device_kind}, backend {eng.cim.backend}, "
+          f"interpret={eng.cim.interpret})")
     print("sample output tokens:", done[0].out_tokens)
 
 

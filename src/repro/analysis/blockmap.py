@@ -129,7 +129,7 @@ def _check_coverage_and_maps(cell, m, kdim, n, mode, bm, bn, bk) -> list:
     specs = (
         ("x", (bm, bk), lambda i, j, k: (i, k), (mp, kp)),
         ("w", (bkw, bn), lambda i, j, k: (k, j), (kwp, np_)),
-        ("scale", (bn,), lambda i, j, k: (j,), (np_,)),
+        ("scale", (1, bn), lambda i, j, k: (0, j), (1, np_)),
         ("out", (bm, bn), lambda i, j, k: (i, j), (mp, np_)),
     )
     corners = itertools.product(*((0, g - 1) for g in grid))

@@ -33,8 +33,8 @@ from . import abscache
 PASS = "jaxpr"
 
 _BANNED_PRIMITIVES = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "infeed", "outfeed",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback", "infeed", "outfeed",
 })
 
 _BANNED_DTYPES = ("float64", "complex128")
@@ -42,7 +42,7 @@ _BANNED_DTYPES = ("float64", "complex128")
 
 def _subjaxprs(value) -> Iterator:
     from jax.extend import core as jex_core
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jex_core.ClosedJaxpr):
         yield value.jaxpr
     elif isinstance(value, jex_core.Jaxpr):
         yield value
@@ -157,7 +157,7 @@ def _injected_entry(inject: str):
     if inject == "widen":
         def build(_model):
             def widen(a):
-                with jax.experimental.enable_x64():
+                with jax.enable_x64(True):
                     return (a.astype(jnp.float64).sum(),)
             return jax.jit(widen), (x,)
         return AuditedEntry("injected.widen", build, (), 1)

@@ -2,8 +2,9 @@
 
 Each module defines CONFIG (the exact published configuration from the
 assignment table) and SMOKE (a reduced same-family configuration used by
-CPU smoke tests).  Full configs are exercised ONLY via the dry-run
-(ShapeDtypeStruct; no allocation).
+CPU smoke tests).  On the CPU, full configs are only lowered by the
+dry-run (ShapeDtypeStruct; no allocation); on a TPU, ``chip_smoke.py``
+serves internlm2-1.8b's full config.
 """
 from __future__ import annotations
 

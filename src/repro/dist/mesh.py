@@ -85,7 +85,12 @@ def make_mesh(spec: MeshSpec, devices=None) -> jax.sharding.Mesh:
     before jax initializes.
     """
     if devices is None:
-        return jax.make_mesh(spec.shape, spec.axes)
+        # Auto axes: the rules engine places arrays with
+        # with_sharding_constraint, which Explicit axes (make_mesh's
+        # default) refuse
+        return jax.make_mesh(
+            spec.shape, spec.axes,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(spec.axes))
     import numpy as np
     arr = np.asarray(devices).reshape(spec.shape)
     return jax.sharding.Mesh(arr, spec.axes)

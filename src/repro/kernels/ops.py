@@ -32,8 +32,8 @@ import warnings
 import jax
 import jax.numpy as jnp
 
-from repro.core.packing import pack_trit_planes_base3, pack_trits2
-from repro.core.ternary import ternarize, trit_range
+from repro.core.packing import pack_base3, pack_trits2
+from repro.core.ternary import quantize_8b_truncate_5t, trit_range
 from .plan import (PACKINGS, check_choice, execute, plan_matmul,
                    shape_of)
 
@@ -88,9 +88,12 @@ def pack_weights(w: jax.Array, mode: str = "base3",
     stack axis (scan-over-layers weights) is supported."""
     check_choice("packing mode", mode, PACKINGS)
     if mode == "base3":
-        tt = ternarize(w, num_trits, axis=-2, method="truncate")
-        data = pack_trit_planes_base3(tt.trits)          # (..., K, N) uint8
-        scale = jnp.squeeze(tt.scale, axis=-2)           # (..., N)
+        # the truncated codes are the 5-trit values themselves: pack
+        # them directly (a balanced-ternary round trip is the identity
+        # on [-121, 121] and would hold five int8 planes per weight)
+        q = quantize_8b_truncate_5t(w, num_trits, axis=-2)
+        data = pack_base3(q.values, num_trits)           # (..., K, N) uint8
+        scale = jnp.squeeze(q.scale, axis=-2)            # (..., N)
     else:
         # single-trit weights: w ~ scale * t, t in {-1,0,1}; threshold at
         # 0.75 * mean|w| per column (standard TWN choice).

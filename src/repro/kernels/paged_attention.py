@@ -108,10 +108,16 @@ def _fused_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref,
         l_s[...] = jnp.zeros(l_s.shape, l_s.dtype)
         acc_s[...] = jnp.zeros(acc_s.shape, acc_s.dtype)
 
-    q = q_ref[0].astype(jnp.float32)                    # (KV, rep, hd)
-    k = k_ref[0].astype(jnp.float32)                    # (ps, KV, hd)
+    q = q_ref[0]                                        # (KV, rep, hd)
+    k = k_ref[0]                                        # (ps, KV, hd)
     v = v_ref[0].astype(jnp.float32)
-    sc = jnp.einsum("krd,tkd->krt", q, k,
+    # the MXU multiplies bf16: one pass is exact for a bf16 pool and
+    # query, f32 operands (and the f32 softmax weights p below) need
+    # HIGHEST, or they are rounded to 8 mantissa bits
+    qk_dtype = jnp.promote_types(q.dtype, k.dtype)
+    sc = jnp.einsum("krd,tkd->krt", q.astype(qk_dtype), k.astype(qk_dtype),
+                    precision=(jax.lax.Precision.HIGHEST
+                               if qk_dtype == jnp.float32 else None),
                     preferred_element_type=jnp.float32)
     kpos = w * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, page_size), 2)
@@ -122,7 +128,8 @@ def _fused_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref,
     corr = jnp.exp(m_prev - m_new)
     l_s[...] = l_s[...] * corr + p.sum(axis=-1)
     acc_s[...] = acc_s[...] * corr[..., None] + jnp.einsum(
-        "krt,tkd->krd", p, v, preferred_element_type=jnp.float32)
+        "krt,tkd->krd", p, v, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     m_s[...] = m_new
 
     @pl.when(w == last_w)
@@ -181,8 +188,10 @@ def paged_attention(q, kv: PagedAttentionKV, *,
 def paged_attention_ref(q, kv: PagedAttentionKV) -> tuple:
     """Gather-based XLA oracle: materializes the dense copy the fused
     kernel avoids, computes the same ``(acc, m, l)`` statistics with a
-    global (single-pass) softmax.  ``m`` matches the kernel bitwise;
-    ``acc``/``l`` to f32 round-off (summation order differs)."""
+    global (single-pass) softmax.  All three match the kernel to f32
+    round-off: the score dot products contract in a different order
+    (so ``m`` differs by about hd * eps relative), and ``acc``/``l``
+    also sum in single-pass rather than per-page order."""
     s, kvh, rep, hd, num_pages, ps, w = _dims(q, kv)
     kg = kv.k_pages[kv.page_table].reshape(s, w * ps, kvh, hd)
     vg = kv.v_pages[kv.page_table].reshape(s, w * ps, kvh, hd)

@@ -21,8 +21,10 @@ does not pad M 16x up to the MXU tile.  Per-output-column scales are
 applied once on the final K step.
 
 Two arithmetic domains:
-  float — dequant to f32 in VMEM, f32 MXU dot (the default; bit-matches
-          the unpack-then-matmul oracle).
+  float — dequant to the activation dtype in VMEM (the trits are exact
+          in bf16), MXU dot accumulating in f32: one pass for bf16
+          activations, HIGHEST for f32 ones (the default; matches the
+          unpack-then-matmul oracle to f32 round-off).
   int8  — ``ternary_matmul_int8``: activations arrive pre-quantized to
           int8 (per-row scales), weights decode to int8 in VMEM, the MXU
           runs an int8 x int8 -> int32 dot and ALL float scaling is
@@ -101,11 +103,22 @@ def _decode_w(w_packed: jax.Array, mode: str, dtype) -> jax.Array:
     """
     if mode == "base3":
         return (w_packed.astype(jnp.int32) - BASE3_OFFSET).astype(dtype)
+    # Mosaic shifts and subtracts only 32-bit integers: widen the byte
+    # first, decode in int32, and narrow to `dtype` last
     kp, bn = w_packed.shape
-    fields = [(w_packed >> (2 * i)) & 0x3 for i in range(TRIT2_PER_BYTE)]
+    w32 = w_packed.astype(jnp.int32)
+    fields = [(w32 >> (2 * i)) & 0x3 for i in range(TRIT2_PER_BYTE)]
     codes = jnp.stack(fields, axis=1)                    # (bk/4, 4, bn)
-    vals = (codes == 1).astype(dtype) - (codes == 2).astype(dtype)
-    return vals.reshape(kp * TRIT2_PER_BYTE, bn)
+    vals = (codes == 1).astype(jnp.int32) - (codes == 2).astype(jnp.int32)
+    return vals.reshape(kp * TRIT2_PER_BYTE, bn).astype(dtype)
+
+
+def _dot_precision(dtype):
+    """MXU precision for a dot whose float operands are ``dtype``.  The
+    MXU multiplies bf16: one pass is exact for bf16 (and narrower)
+    operands, but rounds f32 operands to 8 mantissa bits, so f32 asks
+    for HIGHEST (several passes, f32-accurate)."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def _checked_dims(x: jax.Array, w_packed: jax.Array,
@@ -148,9 +161,10 @@ def _kernel(x_ref, w_ref, scale_ref, o_ref, acc_ref, *, mode: str, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _decode_w(w_ref[...], mode, jnp.float32)         # (bk, bn) f32
-    x = x_ref[...].astype(jnp.float32)                   # (bm, bk)
-    acc_ref[...] += jax.lax.dot(x, w, preferred_element_type=jnp.float32)
+    x = x_ref[...]                                       # (bm, bk)
+    w = _decode_w(w_ref[...], mode, x.dtype)             # (bk, bn)
+    acc_ref[...] += jax.lax.dot(x, w, precision=_dot_precision(x.dtype),
+                                preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _finish():
@@ -175,9 +189,14 @@ def ternary_matmul(x: jax.Array, w_packed: jax.Array, scale: jax.Array,
     m, kdim, n = _checked_dims(x, w_packed, mode)
     abm, abn, abk = select_block_shapes(m, kdim, n, mode)
     bm, bn, bk = bm or abm, bn or abn, bk or abk
-    scale = jnp.broadcast_to(jnp.asarray(scale, x.dtype).reshape(-1), (n,))
+    # the scales stay f32 whatever x is, as in the oracle
+    scale = jnp.broadcast_to(jnp.asarray(scale, jnp.float32).reshape(-1),
+                             (n,))
     x, w_packed, scale, _ = _pad_to_blocks(x, w_packed, scale, mode,
                                            bm, bn, bk)
+    # scales ride as 2-D rows/columns: Mosaic refuses a 1-D f32 block
+    # (its T(128) tiling disagrees with XLA's T(1024) layout)
+    scale = scale.reshape(1, -1)
     mt, nt, kt = x.shape[0] // bm, w_packed.shape[1] // bn, x.shape[1] // bk
     bkw = bk if mode == "base3" else bk // TRIT2_PER_BYTE
 
@@ -187,7 +206,7 @@ def ternary_matmul(x: jax.Array, w_packed: jax.Array, scale: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bkw, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], w_packed.shape[1]), out_dtype),
@@ -214,8 +233,8 @@ def _kernel_int8(x_ref, xs_ref, w_ref, scale_ref, o_ref, acc_ref, *,
     @pl.when(k == nk - 1)
     def _finish():
         o_ref[...] = (acc_ref[...].astype(jnp.float32)
-                      * xs_ref[...].astype(jnp.float32)[:, None]
-                      * scale_ref[...].astype(jnp.float32)[None, :]
+                      * xs_ref[...].astype(jnp.float32)
+                      * scale_ref[...].astype(jnp.float32)
                       ).astype(o_ref.dtype)
 
 
@@ -246,6 +265,7 @@ def ternary_matmul_int8(x_int: jax.Array, x_scale: jax.Array,
                                                 mode, bm, bn, bk)
     if mp:
         x_scale = jnp.pad(x_scale, (0, mp))
+    x_scale, scale = x_scale.reshape(-1, 1), scale.reshape(1, -1)
     mt, nt, kt = (x_int.shape[0] // bm, w_packed.shape[1] // bn,
                   x_int.shape[1] // bk)
     bkw = bk if mode == "base3" else bk // TRIT2_PER_BYTE
@@ -255,9 +275,9 @@ def ternary_matmul_int8(x_int: jax.Array, x_scale: jax.Array,
         grid=(mt, nt, kt),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bm,), lambda i, j, k: (i,)),
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
             pl.BlockSpec((bkw, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((x_int.shape[0], w_packed.shape[1]),

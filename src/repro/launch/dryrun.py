@@ -1,12 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 against the production meshes, and extract the roofline terms.
 
-The two lines above MUST stay the first statements in this file — jax
+The lines above MUST stay the first statements in this file — jax
 locks the host platform device count on first initialization, and the
-dry-run needs 512 placeholder devices for the 2x16x16 multi-pod mesh.
+dry-run needs 512 placeholder devices for the 2x16x16 multi-pod mesh,
+pinned to the CPU even on a host that has an accelerator.
 (Do NOT import this module from tests; run it as a subprocess.)
 
 Usage:

@@ -34,14 +34,18 @@ are comparable.
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
+import os
 import time
 
 import jax
 import jax.numpy as jnp
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Serve one request stream; prints the result as one JSON line and
+    returns the same dict."""
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="internlm2-1.8b")
     p.add_argument("--smoke", action="store_true")
@@ -144,6 +148,8 @@ def main(argv=None):
         p.error("--fidelity device requires --continuous (drift + "
                 "restore-scrub are per-chunk hooks of the Scheduler)")
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if args.frontend:
         return _run_frontend(args, kv)
 
@@ -154,6 +160,7 @@ def main(argv=None):
                              ServeEngine, latency_stats, load_trace,
                              make_trace, poisson_arrivals)
 
+    t_setup = time.monotonic()
     cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
     model = registry.build(cfg)
     params = model.init(jax.random.key(args.seed))
@@ -226,6 +233,7 @@ def main(argv=None):
                            arrival_s=rec["arrival_s"]))
 
     t0 = time.monotonic()
+    setup_s = t0 - t_setup        # weights built and packed, engine ready
     if args.continuous:
         done = eng.run()                      # natively arrival-aware
     else:
@@ -241,12 +249,19 @@ def main(argv=None):
         "generated_tokens": eng.generated_tokens,
         "steps": eng.steps_run,
         "host_transfers": eng.host_transfers,
+        "setup_s": round(setup_s, 2),
         "wall_s": round(dt, 2),
         "tok_per_s": round(eng.generated_tokens / max(dt, 1e-9), 1),
         **latency_stats(done),
     }
+    # digest of every request's tokens, by uid: two runs of the same
+    # requests served the same tokens iff their digests are equal
+    out["tokens_digest"] = hashlib.sha256(json.dumps(
+        sorted((r.uid, r.out_tokens) for r in done)).encode()).hexdigest()
     if cim_decode is not None:
         out["fidelity"] = cim_decode.fidelity
+        # the decode plan request as the engine resolved it
+        out["plan"] = eng.cim.plan_request()
     if args.continuous:
         out.update(decode_loop="continuous", slots=eng.slots,
                    chunk=eng.chunk, chunks=eng.chunks_run,
@@ -261,13 +276,18 @@ def main(argv=None):
                        pages_in_use_peak=eng.allocator.peak_in_use,
                        kv_bytes_pool=eng.kv_bytes(),
                        kv_bytes_resident_peak=eng.kv_bytes_resident_peak,
-                       prefix_hit_rate=round(eng.prefix_hit_rate, 3))
+                       prefix_hit_rate=round(eng.prefix_hit_rate, 3),
+                       attn_plan=(eng.attn_plan.describe()
+                                  if eng.attn_plan else None))
     else:
         out["decode_loop"] = "legacy" if args.legacy_loop else "device"
+    stats = jax.local_devices()[0].memory_stats()
+    out["peak_bytes_in_use"] = (stats or {}).get("peak_bytes_in_use")
     print(json.dumps(out))
+    return out
 
 
-def _run_frontend(args, kv: str) -> None:
+def _run_frontend(args, kv: str) -> dict:
     """The --frontend mode: registry + bounded-queue server + open-loop
     replay, reporting the load-harness stats (goodput, TTFT, latency
     split) plus the registry capacity report."""
@@ -305,7 +325,23 @@ def _run_frontend(args, kv: str) -> None:
            "kv": kv, **report,
            "capacity_report": reg.capacity_report()}
     print(json.dumps(out))
+    return out
+
+
+def pin_bf16_rounding() -> None:
+    """Make XLA round bf16 values where the model says, in every program.
+
+    By default XLA may keep bf16 intermediates in f32 inside a fusion
+    ("excess precision"), so two programs that fuse differently (pallas
+    against xla kernels, a sharded pool against one device) round
+    differently, and their greedy tokens drift apart on the same
+    weights.  Takes effect only before JAX starts its backend."""
+    flag = "--xla_allow_excess_precision=false"
+    flags = os.environ.get("XLA_FLAGS", "")
+    if flag not in flags.split():
+        os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()
 
 
 if __name__ == "__main__":
+    pin_bf16_rounding()
     main()

@@ -38,6 +38,8 @@ def main(argv=None):
                    choices=("float", "ternary", "exact"))
     args = p.parse_args(argv)
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from repro import configs, optim
     from repro.core.cim_linear import CIMConfig
     from repro.data import DataConfig, entropy_floor

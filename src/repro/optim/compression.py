@@ -95,8 +95,7 @@ def int8_allgather_sync(grads: Any, mesh, axes: tuple = ("data",),
                     ).astype(x.dtype)
         return jax.tree.map(one, g)
 
-    from jax.experimental.shard_map import shard_map
     specs = jax.tree.map(lambda _: P(), grads)
-    synced = shard_map(sync, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                       check_rep=False)(grads)
+    synced = jax.shard_map(sync, mesh=mesh, in_specs=(specs,),
+                           out_specs=specs, check_vma=False)(grads)
     return synced, residual

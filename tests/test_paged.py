@@ -503,9 +503,12 @@ def test_attention_plan_capability():
 
 
 def test_paged_attention_kernel_matches_gather_oracle():
-    """The fused kernel's flash statistics against the gather oracle:
-    the running max is bitwise identical; acc/l agree to f32 round-off
-    (online vs single-pass summation order)."""
+    """The fused kernel's flash statistics against the gather oracle, to
+    f32 round-off.  The scores are length-hd dot products that the
+    kernel's per-page einsum and the oracle's gathered einsum contract in
+    different orders, so even the running max ``m`` differs by about
+    hd * eps(f32) relative (~2e-6 at hd=16); ``l``/``acc`` add the
+    online-vs-single-pass summation order on top."""
     from repro.kernels import paged_attention as pa
     s, kvh, rep, hd, ps, w = 3, 2, 3, 16, 8, 4
     key = jax.random.key(11)
@@ -522,7 +525,8 @@ def test_paged_attention_kernel_matches_gather_oracle():
 
     acc, m, l = pa.paged_attention(q, kv, interpret=True)
     acc_r, m_r, l_r = pa.paged_attention_ref(q, kv)
-    np.testing.assert_array_equal(np.asarray(m), np.asarray(m_r))
+    np.testing.assert_allclose(np.asarray(m), np.asarray(m_r),
+                               rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(l), np.asarray(l_r),
                                rtol=1e-6)
     np.testing.assert_allclose(np.asarray(acc), np.asarray(acc_r),

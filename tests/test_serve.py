@@ -147,3 +147,58 @@ def test_latency_stats_interpolates_percentiles():
     qs = [i / 20 for i in range(21)]
     vals = [percentile(lat, q) for q in qs]
     assert vals == sorted(vals)
+
+
+# ------------------------------------------------- launcher entry point
+
+@pytest.fixture
+def cache_config(monkeypatch, tmp_path):
+    """Point the launchers' default compile cache at a scratch dir and
+    restore this process's cache settings afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch import compile_cache
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compile_cache, "REPO_CACHE_DIR",
+                        str(tmp_path / "jax_cache"))
+    compilation_cache.reset_cache()
+    yield tmp_path / "jax_cache"
+    jax.config.update("jax_compilation_cache_dir", saved[0])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      saved[1])
+    compilation_cache.reset_cache()
+
+
+def test_serve_main_returns_printed_result(capsys, cache_config):
+    """``launch.serve.main`` returns the dict it prints; its int8-domain
+    tokens are identical on the pallas and xla backends, one transfer
+    per chunk; the compile cache lands in the launcher's fixed dir."""
+    import json
+    from repro.launch import serve
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    argv = ["--arch", "internlm2-1.8b", "--smoke", "--packed", "trit2",
+            "--domain", "int8", "--continuous", "--kv", "paged",
+            "--requests", "3", "--prompt-len", "8", "--max-new", "5",
+            "--slots", "2", "--chunk", "2", "--capacity", "16",
+            "--page-size", "4"]
+    out = serve.main(argv)
+    assert json.loads(capsys.readouterr().out.splitlines()[-1]) == out
+    ref = serve.main(argv + ["--backend", "xla"])
+    assert out["plan"]["backend"] == "pallas"
+    assert ref["plan"]["backend"] == "xla"
+    assert out["tokens_digest"] == ref["tokens_digest"]
+    assert out["generated_tokens"] == 3 * 5
+    assert out["host_transfers"] == out["chunks"]
+    assert out["attn_plan"] is None      # interpret-only platform: gather
+    assert any(cache_config.iterdir())
+
+
+def test_compile_cache_env_dir_wins(monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and
+    the helper sets nothing."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.configure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before

@@ -180,6 +180,7 @@ def paged_attention(q, kv: PagedAttentionKV, *,
             jax.ShapeDtypeStruct((s, kvh, rep), jnp.float32),
         ],
         interpret=interpret,
+        name="paged_attention",
     )
     acc, m, l = fn(kv.page_table, kv.pos, q, kv.k_pages, kv.v_pages)
     return acc, m, l

@@ -70,6 +70,13 @@ import numpy as np
 _LOG = logging.getLogger("repro.serve.engine")
 
 
+def _span(name: str, **args):
+    """A host span (``serve.*``) on the profiler trace's clock, beside the
+    device's ops; about a microsecond when no profiler runs.  The spans
+    and their nesting are listed in serve/README.md, "Spans"."""
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
 def greedy_sample(logits: jax.Array) -> jax.Array:
     return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
 
@@ -776,16 +783,17 @@ class Scheduler(_EngineBase):
     def _admit(self, req: Request, slot: int) -> bool:
         """Prefill one request and scatter its state into `slot` —
         entirely on device (tok0 is emitted by the next chunk)."""
-        tok0, st = self._prefill(self.params,
-                                 _batch_inputs([req], self.extra_inputs))
-        self.steps_run += 1
-        (self.pool, self.tok, self.live, self.made, self.fresh,
-         self.max_new_row, self.eos_row) = self._admit_fn(
-            self.pool, self.tok, self.live, self.made, self.fresh,
-            self.max_new_row, self.eos_row,
-            jnp.asarray(slot, jnp.int32), st, tok0,
-            jnp.asarray(req.max_new, jnp.int32),
-            jnp.asarray(req.eos_id, jnp.int32))
+        with _span("serve.prefill", uid=req.uid, prompt_len=len(req.prompt)):
+            tok0, st = self._prefill(self.params,
+                                     _batch_inputs([req], self.extra_inputs))
+            self.steps_run += 1
+            (self.pool, self.tok, self.live, self.made, self.fresh,
+             self.max_new_row, self.eos_row) = self._admit_fn(
+                self.pool, self.tok, self.live, self.made, self.fresh,
+                self.max_new_row, self.eos_row,
+                jnp.asarray(slot, jnp.int32), st, tok0,
+                jnp.asarray(req.max_new, jnp.int32),
+                jnp.asarray(req.eos_id, jnp.int32))
         self._slot_req[slot] = req
         return True
 
@@ -808,13 +816,23 @@ class Scheduler(_EngineBase):
         # one scheduling round: <= chunk decode steps on device, then
         # ONE transfer carrying everything the host needs — fidelity
         # extras (ADC clip counters) ride the same transfer
-        occupied = [i for i, r in enumerate(self._slot_req)
-                    if r is not None]
-        self._pre_chunk()
-        buf, cnt, steps, occ = self._run_chunk()
-        self.fresh = jnp.zeros((self.slots,), jnp.bool_)
-        out = self._device_get(
-            (buf, cnt, self.live, steps, occ) + self._round_extras())
+        with _span("serve.round"):
+            with _span("serve.dispatch"):
+                occupied = [i for i, r in enumerate(self._slot_req)
+                            if r is not None]
+                self._pre_chunk()
+                buf, cnt, steps, occ = self._run_chunk()
+                self.fresh = jnp.zeros((self.slots,), jnp.bool_)
+                extras = self._round_extras()
+            with _span("serve.sync"):
+                out = self._device_get(
+                    (buf, cnt, self.live, steps, occ) + extras)
+            with _span("serve.absorb"):
+                self._absorb_round(out, occupied, elapsed)
+
+    def _absorb_round(self, out, occupied, elapsed) -> None:
+        """Host bookkeeping on the round's transfer: counters, each
+        slot's new tokens, retirement, the periodic scrub."""
         buf_h, cnt_h, live_h, steps_h, occ_h = out[:5]
         self._absorb_round_extras(out[5:])
         self.chunks_run += 1
@@ -851,10 +869,10 @@ class Scheduler(_EngineBase):
         (pool full — or, paged, page reservation not coverable yet).
         Stamps ``admit_s`` on success."""
         free = self.free_slots()
-        if not free:
-            return False
-        if not self._admit(req, free[0]):
-            return False
+        with _span("serve.admit", uid=req.uid,
+                   slot=free[0] if free else -1):
+            if not (free and self._admit(req, free[0])):
+                return False
         req.admit_s = now
         return True
 
@@ -1066,7 +1084,6 @@ class PagedScheduler(Scheduler):
 
     # -------------------------------------------------------- admission
     def _admit(self, req: Request, slot: int) -> bool:
-        from repro.models.paged_kv import prefix_key
         ps = self.page_size
         s_len = len(req.prompt)
         # positions written: 0..S-1 (prefill) and S..S+max_new-2
@@ -1087,8 +1104,49 @@ class PagedScheduler(Scheduler):
                 f"pool holds {self.num_pages - 1} usable pages "
                 f"(num_pages={self.num_pages}, page 0 reserved); size "
                 f"num_pages to cover one worst-case request")
+        with _span("serve.reserve", uid=req.uid):
+            pages, shared = self._reserve(req, n_total)
+        if pages is None:
+            return False
+        # device: batch-1 prefill, then scatter its KV into the fresh
+        # pages (shared hits already hold the identical bits)
+        with _span("serve.prefill", uid=req.uid, prompt_len=s_len):
+            tok0, st = self._prefill(self.params,
+                                     _batch_inputs([req], self.extra_inputs))
+            self.steps_run += 1
+            n_prompt = -(-s_len // ps)
+            hit = set(shared)
+            write_src = [j for j in range(n_prompt) if j not in hit]
+            if write_src:
+                self.pool = self._write_pages(
+                    self.pool, st,
+                    jnp.asarray([pages[j] for j in write_src], jnp.int32),
+                    jnp.asarray(write_src, jnp.int32))
+            (self.tok, self.live, self.made, self.fresh, self.max_new_row,
+             self.eos_row, self.pos) = self._admit_fn(
+                self.tok, self.live, self.made, self.fresh,
+                self.max_new_row, self.eos_row, self.pos,
+                jnp.asarray(slot, jnp.int32), tok0,
+                jnp.asarray(req.max_new, jnp.int32),
+                jnp.asarray(req.eos_id, jnp.int32),
+                jnp.asarray(s_len, jnp.int32))
+        row = np.zeros((self.pages_per_slot,), np.int32)
+        row[:n_total] = pages
+        self._page_table[slot] = row
+        self._page_table_dev = None
+        self._slot_pages[slot] = pages
+        self._slot_req[slot] = req
+        return True
+
+    def _reserve(self, req: Request, n_total: int) -> tuple:
+        """Host page reservation for ``req``: prefix-shared pages looked
+        up, the rest allocated all-or-nothing.  Returns ``(pages,
+        shared)``, or ``(None, [])`` with every reference rolled back
+        when the pool cannot cover it."""
+        from repro.models.paged_kv import prefix_key
+        ps = self.page_size
         prompt_np = np.asarray(req.prompt)
-        n_share = s_len // ps if self.share_prefix else 0
+        n_share = len(prompt_np) // ps if self.share_prefix else 0
         pages: list = [None] * n_total
         keys = [prefix_key(prompt_np, j, ps) for j in range(n_share)]
         shared = []
@@ -1105,39 +1163,12 @@ class PagedScheduler(Scheduler):
             self.allocator.release([pages[j] for j in shared])
             self.allocator.prefix_hits -= len(shared)
             self.allocator.prefix_lookups -= n_share
-            return False
+            return None, []
         for j, pid in zip(missing, fresh_ids):
             pages[j] = pid
             if j < n_share:
                 self.allocator.register_prefix(keys[j], pid)
-        # device: batch-1 prefill, then scatter its KV into the fresh
-        # pages (shared hits already hold the identical bits)
-        tok0, st = self._prefill(self.params,
-                                 _batch_inputs([req], self.extra_inputs))
-        self.steps_run += 1
-        n_prompt = -(-s_len // ps)
-        hit = set(shared)
-        write_src = [j for j in range(n_prompt) if j not in hit]
-        if write_src:
-            self.pool = self._write_pages(
-                self.pool, st,
-                jnp.asarray([pages[j] for j in write_src], jnp.int32),
-                jnp.asarray(write_src, jnp.int32))
-        (self.tok, self.live, self.made, self.fresh, self.max_new_row,
-         self.eos_row, self.pos) = self._admit_fn(
-            self.tok, self.live, self.made, self.fresh,
-            self.max_new_row, self.eos_row, self.pos,
-            jnp.asarray(slot, jnp.int32), tok0,
-            jnp.asarray(req.max_new, jnp.int32),
-            jnp.asarray(req.eos_id, jnp.int32),
-            jnp.asarray(s_len, jnp.int32))
-        row = np.zeros((self.pages_per_slot,), np.int32)
-        row[:n_total] = pages
-        self._page_table[slot] = row
-        self._page_table_dev = None
-        self._slot_pages[slot] = pages
-        self._slot_req[slot] = req
-        return True
+        return pages, shared
 
     # ------------------------------------------------------ chunk round
     def _run_chunk(self):

@@ -87,13 +87,33 @@ def test_ternary_matmul_int8_compiles(spec, mode, phase):
              spec((kw, N), jnp.uint8), spec((N,), jnp.float32))
 
 
-def test_paged_attention_compiles(spec):
+def _attention_operands(spec):
     # 8 slots x 16 pages of 16 rows, 8 KV heads x 2 queries, hd 128
     s, w, ps, kvh, rep, hd = 8, 16, 16, 8, 2, 128
     pool = spec((1 + s * w, ps, kvh, hd), jnp.bfloat16)
-    _compile(pa.paged_attention, spec((s, kvh, rep, hd), jnp.bfloat16),
-             pa.PagedAttentionKV(pool, pool, spec((s, w), jnp.int32),
-                                 spec((s,), jnp.int32)))
+    return (spec((s, kvh, rep, hd), jnp.bfloat16),
+            pa.PagedAttentionKV(pool, pool, spec((s, w), jnp.int32),
+                                spec((s,), jnp.int32)))
+
+
+def test_paged_attention_compiles(spec):
+    _compile(pa.paged_attention, *_attention_operands(spec))
+
+
+def test_paged_attention_keeps_its_name(spec):
+    """Called inside a loop, as the decode step calls it once a layer,
+    the kernel's custom call is still named ``paged_attention``: the
+    name the device trace shows it under (unnamed, ``closed_call``)."""
+    def layers(q, kv):
+        def layer(carry, _):
+            acc, _, _ = pa.paged_attention(q, kv)
+            return carry + acc.sum(), None
+        return jax.lax.scan(layer, 0.0, None, length=2)[0]
+    text = _compile(layers, *_attention_operands(spec)).as_text()
+    calls = [line.split(" = ", 1)[0].split()[-1]
+             for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert calls and all(c.startswith("%paged_attention.") for c in calls)
 
 
 def test_cim_mac_compiles(spec):

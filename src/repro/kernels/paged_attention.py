@@ -11,11 +11,35 @@ consumes the page table *in-kernel* through scalar-prefetch BlockSpec
 index maps — grid step ``(s, w)`` DMAs page ``page_table[s, w]`` of the
 pool directly into VMEM, so the gathered dense copy is never built.
 Page 0 is the pool's reserved null page: table rows are padded with 0,
-and the positional mask (``kpos >= pos`` -> -1e30, the same identity
-the dense read uses) provably zeroes whatever the null page holds —
-``exp(-1e30 - m)`` underflows to exactly 0.0 in f32 once any real key
-has been seen, and slots with no live context report ``m = -1e30,
-l = 0`` which the caller's new-token merge renormalizes away.
+and the positional mask (``kpos >= len`` -> -1e30, the same identity
+the dense read uses) provably zeroes whatever the last live page holds
+past ``len``: ``exp(-1e30 - m)`` underflows to exactly 0.0 in f32 once
+any real key has been seen.
+
+Only live cells are visited.  ``len[s]`` (``PagedAttentionKV.pos``) is
+slot ``s``'s live context; a cell is live when ``w * page_size <
+len[s]``:
+
+  * compute: the cell body (scores, mask, online-softmax update) runs
+    under ``pl.when(w * page_size < len[s])``; the ``w == 0`` init and
+    the ``w == last`` flush always run, so every slot writes its
+    statistics;
+  * DMA: the K and V index maps clamp the window to the slot's last
+    live page, ``page_table[s, min(w, max(len[s] - 1, 0) // page_size)]``,
+    so every skipped cell repeats the previous block index and the
+    pipeline issues no fetch for it.
+
+A skipped cell contributed exactly nothing before (its keys are all
+masked: ``p = 0`` and ``corr = 1``), so skipping it changes no bit of
+a live slot's ``(acc, m, l)``; pages past a slot's last live page are
+never read, whatever they hold.  A dead slot is given ``len = 0``: it
+reads page ``page_table[s, 0]`` once (the null page for a retired
+slot's zeroed row) and returns ``acc = 0, m = -1e30, l = 0``, which the
+caller's new-token merge renormalizes away.  When every cell is live
+the kernel does exactly the work of one that visits them all.  The
+paged scheduler counts the cells it computes against the grid
+(``PagedScheduler.attn_cells_computed`` / ``attn_cells_grid``, per
+layer, from the positions the host tracks).
 
 The kernel runs the pool in per-page streaming (online-softmax) order
 and returns the *partial* flash statistics ``(acc, m, l)`` rather than
@@ -61,6 +85,7 @@ class PagedAttentionKV(NamedTuple):
       k_pages, v_pages : (num_pages, page_size, KV, hd)  one layer's pool
       page_table       : (S, W) int32   pool page id per slot x window
       pos              : (S,) int32     live context length per slot
+                                        (0 for a dead slot)
     """
     k_pages: jax.Array
     v_pages: jax.Array
@@ -93,44 +118,48 @@ def _dims(q, kv) -> tuple:
     return s, kvh, rep, hd, num_pages, ps, w
 
 
-def _fused_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref,
+def _fused_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
                   acc_ref, m_ref, l_ref, m_s, l_s, acc_s, *,
                   page_size: int, last_w: int):
     """One grid step = one (slot, page-window) cell.  ``k_ref``/``v_ref``
     hold page ``page_table[s, w]`` (the index map did the routing); the
-    VMEM scratch carries the online-softmax state across the w axis."""
+    VMEM scratch carries the online-softmax state across the w axis.
+    Cells at or past the slot's live length ``len_ref[s]`` are skipped."""
     s = pl.program_id(0)
     w = pl.program_id(1)
 
     @pl.when(w == 0)
     def _init():
-        m_s[...] = jnp.full(m_s.shape, -jnp.inf, m_s.dtype)
+        m_s[...] = jnp.full(m_s.shape, NEG_INF, m_s.dtype)
         l_s[...] = jnp.zeros(l_s.shape, l_s.dtype)
         acc_s[...] = jnp.zeros(acc_s.shape, acc_s.dtype)
 
-    q = q_ref[0]                                        # (KV, rep, hd)
-    k = k_ref[0]                                        # (ps, KV, hd)
-    v = v_ref[0].astype(jnp.float32)
-    # the MXU multiplies bf16: one pass is exact for a bf16 pool and
-    # query, f32 operands (and the f32 softmax weights p below) need
-    # HIGHEST, or they are rounded to 8 mantissa bits
-    qk_dtype = jnp.promote_types(q.dtype, k.dtype)
-    sc = jnp.einsum("krd,tkd->krt", q.astype(qk_dtype), k.astype(qk_dtype),
-                    precision=(jax.lax.Precision.HIGHEST
-                               if qk_dtype == jnp.float32 else None),
-                    preferred_element_type=jnp.float32)
-    kpos = w * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, page_size), 2)
-    sc = jnp.where(kpos < pos_ref[s], sc, NEG_INF)
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, sc.max(axis=-1))
-    p = jnp.exp(sc - m_new[..., None])
-    corr = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * corr + p.sum(axis=-1)
-    acc_s[...] = acc_s[...] * corr[..., None] + jnp.einsum(
-        "krt,tkd->krd", p, v, precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
-    m_s[...] = m_new
+    @pl.when(w * page_size < len_ref[s])
+    def _cell():
+        q = q_ref[0]                                    # (KV, rep, hd)
+        k = k_ref[0]                                    # (ps, KV, hd)
+        v = v_ref[0].astype(jnp.float32)
+        # the MXU multiplies bf16: one pass is exact for a bf16 pool and
+        # query, f32 operands (and the f32 softmax weights p below) need
+        # HIGHEST, or they are rounded to 8 mantissa bits
+        qk_dtype = jnp.promote_types(q.dtype, k.dtype)
+        sc = jnp.einsum("krd,tkd->krt", q.astype(qk_dtype),
+                        k.astype(qk_dtype),
+                        precision=(jax.lax.Precision.HIGHEST
+                                   if qk_dtype == jnp.float32 else None),
+                        preferred_element_type=jnp.float32)
+        kpos = w * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, page_size), 2)
+        sc = jnp.where(kpos < len_ref[s], sc, NEG_INF)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, sc.max(axis=-1))
+        p = jnp.exp(sc - m_new[..., None])
+        corr = jnp.exp(m_prev - m_new)
+        l_s[...] = l_s[...] * corr + p.sum(axis=-1)
+        acc_s[...] = acc_s[...] * corr[..., None] + jnp.einsum(
+            "krt,tkd->krd", p, v, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        m_s[...] = m_new
 
     @pl.when(w == last_w)
     def _flush():
@@ -139,31 +168,43 @@ def _fused_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref,
         l_ref[0] = l_s[...]
 
 
+def _live_page(pt, lens, i, j, page_size: int):
+    """The pool page cell ``(i, j)`` reads: window ``j`` clamped to slot
+    ``i``'s last live page, so a skipped cell repeats the block index
+    before it and is not fetched."""
+    last = jnp.maximum(lens[i] - 1, 0) // page_size
+    return pt[i, jnp.minimum(j, last)]
+
+
 def paged_attention(q, kv: PagedAttentionKV, *,
                     interpret: bool = False) -> tuple:
     """Flash statistics of ``q`` against the paged context: returns
     ``(acc, m, l)`` with shapes ``(S, KV, rep, hd)`` / ``(S, KV, rep)``
     x2, all f32; ``out = acc / l[..., None]`` after the caller's
-    new-token merge."""
+    new-token merge.  A slot with ``kv.pos == 0`` returns ``acc = 0,
+    m = -1e30, l = 0``."""
     s, kvh, rep, hd, num_pages, ps, w = _dims(q, kv)
+    page = functools.partial(_live_page, page_size=ps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,              # (page_table, pos)
+        num_scalar_prefetch=2,              # (page_table, len)
         grid=(s, w),
         in_specs=[
             pl.BlockSpec((1, kvh, rep, hd),
-                         lambda i, j, pt, pos: (i, 0, 0, 0)),
+                         lambda i, j, pt, lens: (i, 0, 0, 0)),
             pl.BlockSpec((1, ps, kvh, hd),
-                         lambda i, j, pt, pos: (pt[i, j], 0, 0, 0)),
+                         lambda i, j, pt, lens: (page(pt, lens, i, j),
+                                                 0, 0, 0)),
             pl.BlockSpec((1, ps, kvh, hd),
-                         lambda i, j, pt, pos: (pt[i, j], 0, 0, 0)),
+                         lambda i, j, pt, lens: (page(pt, lens, i, j),
+                                                 0, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, kvh, rep, hd),
-                         lambda i, j, pt, pos: (i, 0, 0, 0)),
+                         lambda i, j, pt, lens: (i, 0, 0, 0)),
             pl.BlockSpec((1, kvh, rep),
-                         lambda i, j, pt, pos: (i, 0, 0)),
+                         lambda i, j, pt, lens: (i, 0, 0)),
             pl.BlockSpec((1, kvh, rep),
-                         lambda i, j, pt, pos: (i, 0, 0)),
+                         lambda i, j, pt, lens: (i, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((kvh, rep), jnp.float32),
@@ -202,7 +243,9 @@ def paged_attention_ref(q, kv: PagedAttentionKV) -> tuple:
     valid = jnp.arange(w * ps, dtype=jnp.int32)[None, :] < kv.pos[:, None]
     sc = jnp.where(valid[:, None, None, :], sc, NEG_INF)
     m = sc.max(axis=-1)
-    p = jnp.exp(sc - m[..., None])
+    # masked keys weigh exactly 0 (as exp(-1e30 - m) does once a live
+    # key sets m), so a slot with no live key reads l = 0, acc = 0
+    p = jnp.where(valid[:, None, None, :], jnp.exp(sc - m[..., None]), 0.0)
     l = p.sum(axis=-1)
     acc = jnp.einsum("skrt,stkd->skrd", p, vg.astype(jnp.float32),
                      preferred_element_type=jnp.float32)

@@ -277,6 +277,8 @@ def main(argv=None) -> dict:
                        kv_bytes_pool=eng.kv_bytes(),
                        kv_bytes_resident_peak=eng.kv_bytes_resident_peak,
                        prefix_hit_rate=round(eng.prefix_hit_rate, 3),
+                       attn_cells_computed=eng.attn_cells_computed,
+                       attn_cells_grid=eng.attn_cells_grid,
                        attn_plan=(eng.attn_plan.describe()
                                   if eng.attn_plan else None))
     else:

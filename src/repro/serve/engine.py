@@ -283,7 +283,9 @@ def make_paged_decode_loop(model, chunk: int, cim=None, spmd_axes=None,
     planned executor consumes the page table in-kernel — the gathered
     dense KV copy the vmapped ``slot_view`` path materializes per slot
     per step never exists.  Token outputs stay bitwise identical at the
-    argmax (tests/test_paged.py pins fused == gather == dense).
+    argmax (tests/test_paged.py pins fused == gather == dense).  Dead
+    slots read with length 0 there, so the kernel visits none of their
+    pages (kernels/paged_attention.py).
     """
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")
@@ -323,7 +325,11 @@ def make_paged_decode_loop(model, chunk: int, cim=None, spmd_axes=None,
         def body(carry):
             step, tok, pool, pos, live, buf, cnt, made, occ = carry
             occ = occ + jnp.sum(live.astype(jnp.int32))
-            tok_new, kts, vts = vread(params, pool, tok, page_table, pos)
+            # the fused read skips every cell of a dead slot, given as
+            # no context; live slots read (and RoPE) their own position
+            read_pos = pos if attn_plan is None else jnp.where(live, pos, 0)
+            tok_new, kts, vts = vread(params, pool, tok, page_table,
+                                      read_pos)
             pool = paged_kv.append_tokens(pool, kts, vts, page_table,
                                           pos, live)
             tok = tok_new
@@ -816,7 +822,7 @@ class Scheduler(_EngineBase):
         # one scheduling round: <= chunk decode steps on device, then
         # ONE transfer carrying everything the host needs — fidelity
         # extras (ADC clip counters) ride the same transfer
-        with _span("serve.round"):
+        with _span("serve.round") as round_span:
             with _span("serve.dispatch"):
                 occupied = [i for i, r in enumerate(self._slot_req)
                             if r is not None]
@@ -828,11 +834,14 @@ class Scheduler(_EngineBase):
                 out = self._device_get(
                     (buf, cnt, self.live, steps, occ) + extras)
             with _span("serve.absorb"):
-                self._absorb_round(out, occupied, elapsed)
+                args = self._absorb_round(out, occupied, elapsed)
+            if args:
+                round_span.set_metadata(**args)
 
-    def _absorb_round(self, out, occupied, elapsed) -> None:
+    def _absorb_round(self, out, occupied, elapsed) -> Optional[dict]:
         """Host bookkeeping on the round's transfer: counters, each
-        slot's new tokens, retirement, the periodic scrub."""
+        slot's new tokens, retirement, the periodic scrub.  Returns the
+        round's args for its ``serve.round`` span, if any."""
         buf_h, cnt_h, live_h, steps_h, occ_h = out[:5]
         self._absorb_round_extras(out[5:])
         self.chunks_run += 1
@@ -899,6 +908,13 @@ class Scheduler(_EngineBase):
         maximize."""
         total = self.slots * self.decode_steps
         return self.occupied_slot_steps / total if total else 0.0
+
+
+def _ceil_sum(n: int, ps: int) -> int:
+    """``sum(ceil(x / ps) for x in 1..n)``: ``ps`` terms of each whole
+    block ``k = 1..q``, then ``r`` terms of ``q + 1``."""
+    q, r = divmod(n, ps)
+    return ps * q * (q + 1) // 2 + r * (q + 1)
 
 
 class PagedScheduler(Scheduler):
@@ -1059,6 +1075,10 @@ class PagedScheduler(Scheduler):
         # retire edits it (not on every steady-state chunk)
         self._page_table_dev = None
         self._slot_pages: list[list] = [[] for _ in range(self.slots)]
+        # fused read only: (slot, page) cells of the kernel's grid, per
+        # layer, and those it computed (the rest it skips)
+        self.attn_cells_computed = 0
+        self.attn_cells_grid = 0
 
     # ------------------------------------------------------ accounting
     def kv_bytes(self) -> int:
@@ -1180,6 +1200,30 @@ class PagedScheduler(Scheduler):
             self.pos, self.live, self.made, self.fresh,
             self.max_new_row, self.eos_row)
         return buf, cnt, steps, occ
+
+    def _absorb_round(self, out, occupied, elapsed) -> Optional[dict]:
+        """Counts the fused read's cells before the base bookkeeping
+        extends each request's tokens: the decode step that made token
+        ``i`` (``i >= 1``; token 0 is the prefill's) read ``len = prompt
+        + i - 1`` positions, ``ceil(len / page_size)`` live cells."""
+        if self.attn_plan is None:
+            return super()._absorb_round(out, occupied, elapsed)
+        cnt_h, steps_h = out[1], int(out[3])
+        computed = 0
+        for s in occupied:
+            req = self._slot_req[s]
+            n0 = len(req.out_tokens)
+            # this round's tokens i in [max(n0, 1), n0 + cnt) read
+            # lens in [lo, hi), lo >= 1
+            lo = len(req.prompt) + max(n0, 1) - 1
+            hi = len(req.prompt) + n0 + int(cnt_h[s]) - 1
+            computed += (_ceil_sum(hi - 1, self.page_size)
+                         - _ceil_sum(lo - 1, self.page_size))
+        grid = steps_h * self.slots * self.pages_per_slot
+        self.attn_cells_computed += computed
+        self.attn_cells_grid += grid
+        super()._absorb_round(out, occupied, elapsed)
+        return {"computed": computed, "grid": grid}
 
     def _retire_slot(self, slot: int) -> None:
         self.allocator.release(self._slot_pages[slot])

@@ -441,6 +441,54 @@ def test_fused_attn_token_parity():
     assert got_f == got_g == dense
 
 
+def test_fused_attn_cell_counters_match_hand_count(tmp_path):
+    """The fused read's cell counters against a hand count, and its
+    tokens against the gather path and the dense pool, with a slot that
+    retires mid-chunk and is re-admitted while the other stays live.
+
+    page_size 4, 2 slots x 8 pages; a decode step that makes token i
+    reads len = prompt + i - 1 positions, ceil(len / 4) cells:
+      round 1 (4 steps): uid 0 (prompt 6) lens 6, 7 then retires -> 2+2;
+                         uid 1 (prompt 5) lens 5..8 -> 2+2+2+2
+      round 2 (4 steps): uid 2 (prompt 9) re-admitted into slot 0,
+                         lens 9..11 -> 3+3+3; uid 1 lens 9..12 -> 4 x 3
+      round 3 (1 step):  uid 1 len 13 -> 4
+    grid = steps x slots x pages = (4 + 4 + 1) x 2 x 8."""
+    cfg, model, params = _setup()
+    specs = [(0, 6, 3), (1, 5, 10), (2, 9, 4)]
+
+    def paged(fused):
+        return PagedScheduler(model, params, capacity=32, slots=2,
+                              chunk=4, page_size=4, fused_attn=fused)
+
+    fused = paged(True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        got_f = _run(fused, _requests(cfg, specs))
+    finally:
+        jax.profiler.stop_trace()
+    assert fused.chunks_run == 3
+    assert fused.attn_cells_computed == (4 + 8) + (9 + 12) + 4
+    assert fused.attn_cells_grid == 9 * 2 * 8
+    gather = paged(False)
+    got_g = _run(gather, _requests(cfg, specs))
+    assert gather.attn_cells_computed == gather.attn_cells_grid == 0
+    dense = _run(Scheduler(model, params, capacity=32, slots=2, chunk=4),
+                 _requests(cfg, specs))
+    assert got_f == got_g == dense
+
+    # each round's counts ride its serve.round span as args
+    from jax.profiler import ProfileData
+    path = next(os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+                for f in fs if f.endswith(".xplane.pb"))
+    rounds = sorted((ev.start_ns, dict(ev.stats))
+                    for plane in ProfileData.from_file(path).planes
+                    for line in plane.lines for ev in line.events
+                    if ev.name == "serve.round")
+    assert [(a["computed"], a["grid"]) for _, a in rounds] == [
+        (12, 64), (21, 64), (4, 16)]
+
+
 def test_fused_attn_auto_falls_back_on_interpret_platform(caplog):
     """'auto' must not serve wallclock through the interpret-mode
     emulation: on a platform without a real lowering it takes the
@@ -502,13 +550,23 @@ def test_attention_plan_capability():
                     domain="int8", kv_layout="paged")
 
 
-def test_paged_attention_kernel_matches_gather_oracle():
+@pytest.mark.parametrize("lens,nan_past_live", [
+    ([29, 17, 32], False),      # every slot live, page-unaligned too
+    ([0, 16, 13], True),        # dead, page-aligned, mid-page
+], ids=["all_live", "skips_dead_cells"])
+def test_paged_attention_kernel_matches_gather_oracle(lens, nan_past_live):
     """The fused kernel's flash statistics against the gather oracle, to
     f32 round-off.  The scores are length-hd dot products that the
     kernel's per-page einsum and the oracle's gathered einsum contract in
     different orders, so even the running max ``m`` differs by about
     hd * eps(f32) relative (~2e-6 at hd=16); ``l``/``acc`` add the
-    online-vs-single-pass summation order on top."""
+    online-vs-single-pass summation order on top.
+
+    With ``nan_past_live`` every page past a slot's last live page (all
+    of a dead slot's) holds NaN for the kernel, finite values for the
+    oracle (which gathers every page): the kernel must neither fetch
+    them into its sums nor compute their cells, and a dead slot
+    (``len = 0``) reads ``l = 0, acc = 0``."""
     from repro.kernels import paged_attention as pa
     s, kvh, rep, hd, ps, w = 3, 2, 3, 16, 8, 4
     key = jax.random.key(11)
@@ -520,17 +578,29 @@ def test_paged_attention_kernel_matches_gather_oracle():
     v_pages = jax.random.normal(jax.random.fold_in(key, 2), pool_shape,
                                 jnp.float32)
     table = jnp.arange(1, 1 + s * w, dtype=jnp.int32).reshape(s, w)
-    pos = jnp.asarray([29, 17, 32], jnp.int32)     # page-unaligned too
+    pos = jnp.asarray(lens, jnp.int32)
     kv = pa.PagedAttentionKV(k_pages, v_pages, table, pos)
-
-    acc, m, l = pa.paged_attention(q, kv, interpret=True)
     acc_r, m_r, l_r = pa.paged_attention_ref(q, kv)
+
+    if nan_past_live:
+        n_live = -(-pos // ps)                          # live pages a slot
+        past = np.asarray(table)[np.arange(w)[None, :]
+                                 >= np.asarray(n_live)[:, None]]
+        kv = kv._replace(k_pages=k_pages.at[past].set(jnp.nan),
+                         v_pages=v_pages.at[past].set(jnp.nan))
+    acc, m, l = pa.paged_attention(q, kv, interpret=True)
+    for x in (acc, m, l):
+        assert np.isfinite(np.asarray(x)).all()
     np.testing.assert_allclose(np.asarray(m), np.asarray(m_r),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(l), np.asarray(l_r),
                                rtol=1e-6)
     np.testing.assert_allclose(np.asarray(acc), np.asarray(acc_r),
                                rtol=1e-5, atol=1e-5)
+    for i in np.flatnonzero(np.asarray(pos) == 0):
+        assert (np.asarray(l[i]) == 0).all()
+        assert (np.asarray(acc[i]) == 0).all()
+        assert (np.asarray(m[i]) == pa.NEG_INF).all()
 
 
 # ------------------------------------------------- bench contract

@@ -3,10 +3,10 @@
 A request's tokens after its first come from decode steps (the first
 from its prefill); the step that produced token ``j`` (``j >= 1``)
 attended ``prompt_len + j`` positions.  The window's steps are those
-whose tokens arrived between its opening and its close."""
+whose tokens arrived between its opening and its close.  What a step's
+work is follows the configuration's architecture module
+(``bench/archs/``)."""
 from __future__ import annotations
-
-from . import work
 
 
 def of(run) -> tuple:
@@ -16,8 +16,8 @@ def of(run) -> tuple:
         a, b = max(r.tokens_at_open, 1), r.tokens_at_close
         if b > a:
             rows += b - a
-            contexts.append((b - a) * r.prompt_len + (a + b - 1) * (b - a)
-                            // 2)
+            contexts.extend(range(r.prompt_len + a, r.prompt_len + b))
+    mod, arch = run.module, run.arch
     steps = run.counters["decode_steps"]
-    return (work.decode_matmul(run.arch, run.packing, rows, steps),
-            work.attention(run.arch, contexts))
+    return (mod.decode_matmul(arch, run.packing, rows, steps, run.counters),
+            mod.attention(arch, contexts))

@@ -6,9 +6,14 @@ Everything that belongs to one configuration, traffic mix or metric is a
 file found by its name in ``BENCHMARK.json``:
 
 - ``bench/configs/<config>.json``: the model's published ``config.json``
-  numbers under their own names, the packing and domain, the
-  scheduler's sizes and the limits of the check;
-- ``bench/traffic/<traffic>.json``: the parameters ``traffic.py`` reads;
+  numbers under their own names, the architecture module that reads
+  them (``"architecture"``, ``dense`` when absent), the packing and
+  domain, the scheduler's sizes and the limits of the check;
+- ``bench/archs/<module>.py``: the architecture's model, reference block
+  and work counts (``bench/archs/__init__.py`` has the interface);
+- ``bench/traffic/<traffic>.json``: the parameters ``traffic.py`` reads,
+  and optionally the cell's own ``capacity`` and ``slots`` in place of
+  the configuration's;
 - ``bench/metrics/<metric>.py``: a reader ``read(run) -> float | None``
   of one metric from the ``Run`` record below.
 
@@ -31,8 +36,11 @@ import shutil
 import sys
 import time
 
+from . import archs
+
 BENCH_DIR = "bench"
 DRAIN_S = 150.0          # how long after the close a due request may take
+CELL_SIZES = ("capacity", "slots")   # a traffic file may set these
 
 
 class CellError(RuntimeError):
@@ -48,6 +56,20 @@ class Cell:
     chips: int
     metrics: list          # [(name, unit, reader)] for --trace 0
     layer_metrics: list    # [(name, unit, reader)] for --trace 1
+
+    @property
+    def module(self):
+        """The configuration's architecture module."""
+        return archs.load(self.config, self.root)
+
+    @property
+    def sched(self) -> dict:
+        """The scheduler's sizes: the configuration's, with the traffic
+        file's ``capacity`` and ``slots`` where it gives them; the pool's
+        pages are always the configuration's."""
+        return dict(self.config["scheduler"],
+                    **{k: self.traffic[k] for k in CELL_SIZES
+                       if k in self.traffic})
 
 
 @dataclasses.dataclass
@@ -83,8 +105,12 @@ class Run:
     trace_window: tuple = None      # (start_ns, end_ns)
 
     @property
+    def module(self):
+        return self.cell.module
+
+    @property
     def arch(self) -> dict:
-        return arch(self.cell.config)
+        return self.module.arch(self.cell.config)
 
     @property
     def packing(self) -> str:
@@ -127,34 +153,32 @@ def load_cell(root: str, workload: str) -> Cell:
         return [(m["name"], m["unit"], _load_reader(root, m["name"]))
                 for m in metrics if workload in m.get("workloads",
                                                       [workload])]
-    return Cell(workload, root, config, traffic, int(wl["chips"]),
+    cell = Cell(workload, root, config, traffic, int(wl["chips"]),
                 mine(bench["end_to_end"]), mine(bench["per_layer"]))
+    archs.find(config, root)        # a missing module fails before JAX starts
+    if any(k in traffic for k in CELL_SIZES):
+        # admission reserves a request's pages in full and nothing is
+        # preempted: every slot must be able to hold a whole request
+        s = cell.sched
+        need = s["slots"] * -(-s["capacity"] // s["page_size"])
+        if need > s["num_pages"] - 1:
+            raise CellError(
+                f"{s['slots']} slots of {s['capacity']} positions need "
+                f"{need} pages of {s['page_size']}; the pool has "
+                f"{s['num_pages'] - 1} (page 0 is the null page)")
+    return cell
 
 
 # ----------------------------------------------------------------- set-up
 
-ARCH_KEYS = ("hidden_size", "intermediate_size", "num_attention_heads",
-             "num_key_value_heads", "head_dim", "num_hidden_layers",
-             "vocab_size", "rms_norm_eps", "rope_theta", "qk_norm")
-
-
 def arch(config: dict) -> dict:
-    """The model's sizes, under the published config.json's names."""
-    return {k: config[k] for k in ARCH_KEYS}
+    """The model's sizes, as its architecture module gives them."""
+    return archs.load(config).arch(config)
 
 
 def model_config(config: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.models.config import ModelConfig
-    a = arch(config)
-    return ModelConfig(
-        name=config["name"], family="dense",
-        num_layers=a["num_hidden_layers"], d_model=a["hidden_size"],
-        num_heads=a["num_attention_heads"],
-        num_kv_heads=a["num_key_value_heads"], d_ff=a["intermediate_size"],
-        vocab_size=a["vocab_size"], head_dim=a["head_dim"],
-        rope_theta=a["rope_theta"], qk_norm=a["qk_norm"],
-        norm_eps=a["rms_norm_eps"])
+    """The program's ModelConfig, as the architecture module builds it."""
+    return archs.load(config).model_config(config)
 
 
 def check_plan(eng, on_tpu: bool) -> None:
@@ -180,10 +204,11 @@ def build(cell: Cell, seed: int, on_tpu: bool):
     from repro.serve import PagedScheduler
 
     from . import weights
-    c = cell.config
-    model = registry.build(model_config(c))
-    params = weights.served_params(model, c["packing"], seed)
-    sch = c["scheduler"]
+    c, mod = cell.config, cell.module
+    model = registry.build(mod.model_config(c))
+    params = weights.served_params(model, c["packing"], seed,
+                                   getattr(mod, "draw", None))
+    sch = cell.sched
     eng = PagedScheduler(
         model, params, capacity=sch["capacity"], slots=sch["slots"],
         chunk=sch["chunk"], page_size=sch["page_size"],
@@ -346,6 +371,8 @@ class Pump:
             if self.at_open is not None and self.at_close is None:
                 self.pages.append((eng.allocator.pages_in_use, sum(
                     logs[i].prompt_len + logs[i].tokens for i in active)))
+        if self.at_open is None:      # all served before the window opened
+            self._open(on_open)
         if self.at_close is None:
             self._close(on_close)
 
@@ -357,8 +384,6 @@ class Pump:
             on_open()
 
     def _close(self, on_close) -> None:
-        if self.at_open is None:           # nothing ran: an empty window
-            self.at_open = _counters(self.eng)
         self.at_close = _counters(self.eng)
         for log in self.logs:
             log.tokens_at_close = log.tokens
@@ -530,7 +555,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     late = sorted(log.offered_s - log.due_s for log in due
                   if not math.isnan(log.offered_s))
     pages = pump.pages or [(0, 0)]
-    page_size = cell.config["scheduler"]["page_size"]
+    page_size = cell.sched["page_size"]
     print(f"kv: pages reserved in the window peak "
           f"{max(p for p, _ in pages)}, mean "
           f"{sum(p for p, _ in pages) / len(pages):.1f} of "
@@ -541,9 +566,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     gc.collect()
     t_ref = time.monotonic()
     gaps = reference.logit_gaps(
-        arch(cell.config), cell.config["packing"], seed, seqs,
-        limits["sample_requests"], cell.config["scheduler"]["capacity"],
-        control=control) if seqs else {}
+        cell.config, seed, seqs, limits["sample_requests"],
+        cell.sched["capacity"], control=control,
+        root=cell.root) if seqs else {}
     print(f"reference: {time.monotonic() - t_ref:.2f} s over "
           f"{len(seqs)} requests", file=sys.stderr)
     gap = gaps.get("control_gap" if control else "served_gap", math.inf)

@@ -1,14 +1,11 @@
 """Roofline time of the decode steps' attention over their live KV
 positions over the device time of the fused paged-attention kernel's
-runs inside ``chunk_step``.
-
-The kernel has no name of its own in the trace yet (the jit that calls
-it is inlined, so it shows as ``%closed_call.<n>``): it is the Pallas
-kernel of the decode step that is not the ternary matmul."""
+runs inside ``chunk_step`` (``jit_chunk_step:%paged_attention.<n>
+[pallas]`` in the trace)."""
 
 
 def is_kernel(name: str) -> bool:
-    return name.endswith("[pallas]") and ":%ternary_matmul" not in name
+    return ":%paged_attention" in name and name.endswith("[pallas]")
 
 
 def read(run):

@@ -1,11 +1,10 @@
 """Plain reference of the served model, and the gap that decides ``correct``.
 
-The model is the published decoder (internlm2 / Qwen3): token embedding,
-then per layer a pre-norm grouped-query attention with rotary positions
-(rotate-half form; Qwen3 adds an RMSNorm on each head's q and k before the
-rotation) and a pre-norm SwiGLU MLP, both added to the residual stream,
-then a final RMSNorm and the output head.  Every matrix is a ternary code
-matrix with per-column scales, and its input is quantized per row, as the
+The model is the published decoder: token embedding, then the blocks of
+its architecture module (``bench/archs/<module>.py``, ``layer``; the
+dense GQA decoder of internlm2 and Qwen3 is ``dense``), then a final
+RMSNorm and the output head.  Every matrix is a ternary code matrix with
+per-column scales, and its input is quantized per row, as the
 configuration states: ``x ~ round(x / s) * s`` with ``s = max|x| / 127``.
 The products of codes are integers and are summed exactly in int32.
 Everything else is float32 at ``highest`` precision.
@@ -26,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import archs
 from . import weights as W
 
 ROW_BLOCK = 256          # positions per block of the output head
@@ -56,53 +56,11 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _layer(x, key, layer, arch, packing, act_bits):
-    """One decoder layer over ``x`` (B, T, d), one sequence at a time so
-    that a batch of long rows fits beside the layer's weights."""
-    d, h, kvh, hd = (arch["hidden_size"], arch["num_attention_heads"],
-                     arch["num_key_value_heads"], arch["head_dim"])
-    f, eps = arch["intermediate_size"], arch["rms_norm_eps"]
-    t = x.shape[1]
-
-    def mat(name, k, n):
-        c, s = W.codes(key, "blocks/" + name, layer, k, n, packing)
-        return lambda v: _qmatmul(v, c, s, act_bits)
-
-    def gain(name, n):
-        return W.gains(key, "blocks/" + name, layer, n, jnp.bfloat16)
-
-    wq, wk, wv = mat("wq", d, h * hd), mat("wk", d, kvh * hd), \
-        mat("wv", d, kvh * hd)
-    wo, w1, w3, w2 = mat("wo", h * hd, d), mat("w1", d, f), \
-        mat("w3", d, f), mat("w2", f, d)
-    g1, g2 = gain("ln1", d), gain("ln2", d)
-    if arch["qk_norm"]:
-        gq, gk = gain("q_norm", hd), gain("k_norm", hd)
-    causal = jnp.tril(jnp.ones((t, t), bool))
-
-    def row(xs):                                # (T, d)
-        a = _rms(xs, g1, eps)
-        q = wq(a).reshape(t, h, hd)
-        k = wk(a).reshape(t, kvh, hd)
-        v = wv(a).reshape(t, kvh, hd)
-        if arch["qk_norm"]:
-            q, k = _rms(q, gq, eps), _rms(k, gk, eps)
-        q, k = _rope(q, arch["rope_theta"]), _rope(k, arch["rope_theta"])
-        qg = q.reshape(t, kvh, h // kvh, hd)
-        sc = jnp.einsum("tgrd,ugd->grtu", qg, k) / np.sqrt(hd)
-        p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
-        o = jnp.einsum("grtu,ugd->tgrd", p, v).reshape(t, h * hd)
-        xs = xs + wo(o)
-        m = _rms(xs, g2, eps)
-        return xs + w2(jax.nn.silu(w1(m)) * w3(m))
-
-    return jax.lax.map(row, x)
-
-
-@functools.partial(jax.jit, static_argnames=("arch", "packing", "bits"))
-def _layer_jit(x, key, layer, arch, packing, bits):
+@functools.partial(jax.jit,
+                   static_argnames=("block", "arch", "packing", "bits"))
+def _layer_jit(x, key, layer, block, arch, packing, bits):
     with jax.default_matmul_precision("highest"):
-        return _layer(x, key, layer, dict(arch), packing, bits)
+        return block(x, key, layer, dict(arch), packing, bits)
 
 
 @functools.partial(jax.jit, static_argnames=("arch", "packing"))
@@ -135,15 +93,19 @@ def _head(xr, xc, pos, tok, key, arch, packing):
     return tuple(o.reshape(-1) for o in out)
 
 
-def logit_gaps(arch: dict, packing: str, seed: int, seqs: list,
-               rows: int, length: int, control: bool = False) -> dict:
+def logit_gaps(config: dict, seed: int, seqs: list, rows: int,
+               length: int, control: bool = False,
+               root: str | None = None) -> dict:
     """``seqs``: at most ``rows`` (prompt, served) pairs of int lists, each
     at most ``length`` tokens in all.  Returns, over every served token,
     the widest gap by which its reference logit lies below the
     reference's best (``served_gap``), and with ``control`` the same for
     the tokens the int4 control would serve (``control_gap``).  Shapes
     are padded to ``rows`` x ``length`` so that every call of a cell
-    runs the same compiled programs."""
+    runs the same compiled programs.  The blocks are those of the
+    architecture module ``config`` names (``archs.load``)."""
+    mod = archs.load(config, root)
+    arch, packing = mod.arch(config), config["packing"]
     key = W.seed_key(seed)
     frozen = tuple(sorted(arch.items()))
     lens = [len(p) + len(s) - 1 for p, s in seqs]
@@ -168,9 +130,9 @@ def logit_gaps(arch: dict, packing: str, seed: int, seqs: list,
     del emb
     xc = xr if control else None
     for layer in range(arch["num_hidden_layers"]):
-        xr = _layer_jit(xr, key, layer, frozen, packing, 8)
+        xr = _layer_jit(xr, key, layer, mod.layer, frozen, packing, 8)
         if control:
-            xc = _layer_jit(xc, key, layer, frozen, packing, 4)
+            xc = _layer_jit(xc, key, layer, mod.layer, frozen, packing, 4)
     best, got, ctl = jax.device_get(_head(
         xr, xc, jnp.asarray(pos, jnp.int32), jnp.asarray(served, jnp.int32),
         key, frozen, packing))

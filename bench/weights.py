@@ -16,7 +16,7 @@ published vocabulary (the program pads it to a multiple of 256) are zero.
 
 ``codes(...)`` is the one generator.  ``served_params`` packs its codes
 into the program's storage layout inside one jitted call (a ``lax.map``
-over layers, so no whole float or int8 tree ever exists);
+over the stacked matrices, so no whole float or int8 tree ever exists);
 ``reference.py`` calls the same generator and never sees the packing.
 """
 from __future__ import annotations
@@ -95,8 +95,14 @@ def _path_name(path) -> str:
     return "/".join(str(getattr(p, "key", p)) for p in path)
 
 
-def served_params(model, packing: str, seed: int):
-    """The served tree, made on the device in one jitted call."""
+def served_params(model, packing: str, seed: int, draw=None):
+    """The served tree, made on the device in one jitted call.
+
+    A packed leaf stacked over leading axes (layers, or layers and
+    experts) is drawn one matrix at a time at the flat row-major index
+    over them.  ``draw(key, name, spec)`` is the architecture module's
+    (``bench/archs/``) for unpacked leaves that are neither the embedding
+    nor norm gains, such as a router; without it they are an error."""
     from repro.kernels.ops import PackedTernary
     cfg = model.cfg
     shapes = abstract_params(model, packing)
@@ -106,16 +112,18 @@ def served_params(model, packing: str, seed: int):
         def leaf(path, spec):
             name = _path_name(path)
             if isinstance(spec, PackedTernary):
-                stacked = spec.data.ndim == 3
+                stack = spec.data.shape[:-2]
                 k, n = spec.shape[-2:]
                 n_valid = cfg.vocab_size if name == "unembed" else None
 
-                def one(layer):
-                    c, s = codes(key, name, layer, k, n, packing, n_valid)
+                def one(i):
+                    c, s = codes(key, name, i, k, n, packing, n_valid)
                     return _pack(c, packing), s
-                if stacked:
+                if stack:
                     data, scale = jax.lax.map(
-                        one, jnp.arange(spec.data.shape[0]))
+                        one, jnp.arange(math.prod(stack)))
+                    data = data.reshape(stack + data.shape[1:])
+                    scale = scale.reshape(stack + scale.shape[1:])
                 else:
                     data, scale = one(0)
                 return PackedTernary(data, scale, spec.mode)
@@ -124,6 +132,8 @@ def served_params(model, packing: str, seed: int):
                                  cfg.vocab_size, spec.dtype)
             if spec.ndim > 2 or (spec.ndim == 2 and not name.startswith(
                     "blocks/")):
+                if draw is not None:
+                    return draw(key, name, spec)
                 raise ValueError(f"{name}: a {spec.shape} matrix the "
                                  f"program does not pack")
             if spec.ndim == 2:                      # stacked norm gains

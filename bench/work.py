@@ -1,5 +1,11 @@
 """Operations and bytes the served model needs, from its configuration.
 
+What every architecture shares is here: a matrix's packed bytes, the
+padded vocabulary, the roofline and the chip's peaks.  Which matrices a
+step reads and how far attention looks are the architecture module's
+(``bench/archs/``); ``matmuls``, ``weight_bytes``, ``decode_matmul`` and
+``attention`` below are the dense module's.
+
 Counted from the algorithm, never from how a kernel is cut: a matmul of
 M rows against a (K, N) weight is 2*M*K*N operations and reads the weight
 once at its packed size (``base3`` one byte a weight, ``trit2`` a quarter,
@@ -15,20 +21,6 @@ import os
 BF16, F32 = 2, 4
 
 
-def matmuls(arch: dict) -> dict:
-    """name -> (count, K, N) of every packed weight matrix."""
-    d, h, kvh, hd, f = (
-        arch["hidden_size"], arch["num_attention_heads"],
-        arch["num_key_value_heads"], arch["head_dim"],
-        arch["intermediate_size"])
-    n_layers = arch["num_hidden_layers"]
-    vp = padded_vocab(arch)
-    return {"wq": (n_layers, d, h * hd), "wk": (n_layers, d, kvh * hd),
-            "wv": (n_layers, d, kvh * hd), "wo": (n_layers, h * hd, d),
-            "w1": (n_layers, d, f), "w3": (n_layers, d, f),
-            "w2": (n_layers, f, d), "unembed": (1, d, vp)}
-
-
 def padded_vocab(arch: dict) -> int:
     return -(-arch["vocab_size"] // 256) * 256
 
@@ -41,37 +33,30 @@ def packed_bytes(k: int, n: int, packing: str) -> int:
     raise ValueError(f"unknown packing {packing!r}")
 
 
+def _dense():
+    from . import archs
+    return archs.load({})
+
+
+def matmuls(arch: dict) -> dict:
+    """The dense architecture's packed matrices (``archs/dense.py``)."""
+    return _dense().matmuls(arch)
+
+
 def weight_bytes(arch: dict, packing: str) -> int:
-    """Device bytes of the served weights: packed matrices and scales,
-    the bf16 embedding and the bf16 norm gains."""
-    d, n_layers = arch["hidden_size"], arch["num_hidden_layers"]
-    total = sum(c * packed_bytes(k, n, packing)
-                for c, k, n in matmuls(arch).values())
-    total += padded_vocab(arch) * d * BF16
-    gains = n_layers * 2 * d + d
-    if arch["qk_norm"]:
-        gains += n_layers * 2 * arch["head_dim"]
-    return total + gains * BF16
+    """The dense architecture's served bytes (``archs/dense.py``)."""
+    return _dense().weight_bytes(arch, packing)
 
 
 def decode_matmul(arch: dict, packing: str, rows: int, steps: int) -> dict:
-    """Matmul work of ``steps`` decode steps that serve ``rows`` live
-    rows in all: every step reads every weight once."""
-    mats = matmuls(arch).values()
-    return {"ops": 2 * rows * sum(c * k * n for c, k, n in mats),
-            "bytes": steps * sum(c * packed_bytes(k, n, packing)
-                                 for c, k, n in mats)}
+    """The dense architecture's decode matmul work (``archs/dense.py``)."""
+    return _dense().decode_matmul(arch, packing, rows, steps)
 
 
 def attention(arch: dict, contexts) -> dict:
-    """Attention work of one decode query per entry of ``contexts``
-    (its number of cached positions), over all layers."""
-    n_layers, h, kvh, hd = (
-        arch["num_hidden_layers"], arch["num_attention_heads"],
-        arch["num_key_value_heads"], arch["head_dim"])
-    pos = sum(contexts)
-    return {"flops": 4 * h * hd * pos * n_layers,
-            "bytes": 2 * kvh * hd * BF16 * pos * n_layers}
+    """The dense architecture's decode attention work
+    (``archs/dense.py``)."""
+    return _dense().attention(arch, contexts)
 
 
 def roofline_s(ops: float, nbytes: float, peak_ops: float,

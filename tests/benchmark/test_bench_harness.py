@@ -90,3 +90,22 @@ def test_backlog_opens_on_a_full_batch(tmp_path):
     # every slot was filled before the window opened
     assert out["attempted"] >= cell.config["scheduler"]["slots"]
     assert out["failed"] == 0
+
+
+def test_backlog_served_out_in_the_pre_roll(tmp_path):
+    """A backlog that the pre-roll serves to its end leaves an empty
+    window: the run closes it as opened, and reads every request."""
+    root = str(tmp_path)
+    name = tiny.write_cell(root)
+    path = os.path.join(root, "bench", "traffic", "tiny.json")
+    slots = tiny.CONFIG["scheduler"]["slots"]
+    with open(path, "w") as f:
+        json.dump(dict(tiny.TRAFFIC, arrival="backlog", requests=slots,
+                       preroll_s=30.0), f)
+    cell = harness.load_cell(root, name)
+    t0 = time.monotonic()
+    out = harness.run_cell(cell, seed=5, seconds=0.5, trace=False,
+                           t_start=t0, on_tpu=False)
+    assert time.monotonic() - t0 < 30.0      # it did not wait for the window
+    assert out["correct"], out["checks"]
+    assert out["attempted"] == slots and out["failed"] == 0

@@ -232,3 +232,24 @@ def test_benchmark_reads_the_metric_in_its_cells(recorded, name, cells):
     want = getattr(sr, name.split(".")[1])(recorded, lo, hi)
     run = SimpleNamespace(trace=tr.load(RECORDED), trace_window=(lo, hi))
     assert read(run) == want
+
+
+def test_paged_attention_kernel_is_matched_by_name(recorded):
+    """The roofline's kernel, matched by its name, is the set of ops the
+    earlier predicate (a Pallas op of ``chunk_step`` that is not the
+    ternary matmul) selected on the recorded window, so the reading does
+    not move; another architecture's kernel is not charged to it."""
+    import importlib.util
+    path = os.path.join(tiny.REPO, "bench", "metrics",
+                        "paged_attention_roofline.py")
+    spec = importlib.util.spec_from_file_location("roofline_reader", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    def before(name):
+        return name.endswith("[pallas]") and ":%ternary_matmul" not in name
+    lo, hi = tr.window(recorded)
+    ops = tr.ops_within(recorded, "chunk_step", lo, hi)
+    now = [o for o in ops if reader.is_kernel(o[0])]
+    assert now and now == [o for o in ops if before(o[0])]
+    assert not reader.is_kernel("jit_chunk_step:%grouped_matmul.3 [pallas]")

@@ -44,3 +44,26 @@ def test_decode_work_counts_weights_once_a_step():
     two = work.decode_matmul(a, "trit2", rows=8, steps=1)
     assert two["ops"] == 2 * one["ops"] and two["bytes"] == one["bytes"]
     assert work.roofline_s(1e12, 819e9, 393e12, 819e9) == pytest.approx(1.0)
+
+
+def test_window_work_is_one_query_per_decode_token():
+    """The window's decode work: a request's token ``j`` (``j >= 1``,
+    its first from prefill) attended ``prompt_len + j`` positions, and
+    only tokens that reached the host inside the window count."""
+    from types import SimpleNamespace
+
+    from bench import archs, decode_work
+    reqs = [SimpleNamespace(prompt_len=10, tokens_at_open=0,
+                            tokens_at_close=4),     # tokens 1, 2, 3
+            SimpleNamespace(prompt_len=7, tokens_at_open=5,
+                            tokens_at_close=7),     # tokens 5, 6
+            SimpleNamespace(prompt_len=3, tokens_at_open=2,
+                            tokens_at_close=2)]     # none in the window
+    a = harness.arch(tiny.CONFIG)
+    run = SimpleNamespace(all_requests=reqs, counters={"decode_steps": 3},
+                          module=archs.load(tiny.CONFIG), arch=a,
+                          packing="trit2")
+    mm, att = decode_work.of(run)
+    assert mm == work.decode_matmul(a, "trit2", rows=5, steps=3)
+    assert att == work.attention(a, [11, 12, 13, 12, 13])
+    assert att["flops"] == 4 * 4 * 32 * 61 * 2

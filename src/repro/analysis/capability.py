@@ -72,6 +72,10 @@ def _eval_operands(op: str, packing: str, shape):
         # no packed weight: the operand is the raw page-pool view
         from repro.kernels import paged_attention
         return paged_attention.eval_operands(shape)
+    if op == "ternary_grouped":
+        # expert-sorted rows against a stack of packed experts
+        from repro.kernels import grouped_matmul
+        return grouped_matmul.eval_operands(shape, packing)
     x = jax.ShapeDtypeStruct((m, k), jnp.float32)
     if op == "cim":
         # float weights: ternarized on the fly by the runner, valid

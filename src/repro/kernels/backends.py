@@ -188,6 +188,37 @@ register_backend(BackendSpec(
     fidelities=_EXACT,
 ))
 
+# ----------------------------------------------- grouped expert matmul
+# op='ternary_grouped': expert-sorted rows against a stack of packed experts
+# (kernels/grouped_matmul.py), int8 domain only.  The Pallas kernel
+# reads each expert's codes at most once a call; the XLA oracle
+# materializes one unpacked expert per tile.
+from . import grouped_matmul as _grouped  # noqa: E402
+
+register_backend(BackendSpec(
+    name="pallas_gmm",
+    ops=frozenset({"ternary_grouped"}),
+    domains=frozenset({"int8"}),
+    packings=frozenset({"base3", "trit2"}),
+    platforms=frozenset({"cpu", "tpu"}),     # cpu = interpret mode
+    priority=100,
+    runner=_grouped.run_pallas,
+    kv_layouts=_ALL_KV_LAYOUTS,
+    fidelities=_EXACT,
+))
+
+register_backend(BackendSpec(
+    name="ref_gmm",
+    ops=frozenset({"ternary_grouped"}),
+    domains=frozenset({"int8"}),
+    packings=frozenset({"base3", "trit2"}),
+    platforms=frozenset({"cpu", "gpu", "tpu"}),
+    priority=10,
+    runner=_grouped.run_ref,
+    kv_layouts=_ALL_KV_LAYOUTS,
+    fidelities=_EXACT,
+))
+
 # The device-fidelity backend (fault-injected analog MAC: sampled
 # conductances + ADC transfer over a seeded FaultModel) registers from
 # repro.faults.backend — imported last so the built-in registrations

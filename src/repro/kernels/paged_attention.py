@@ -29,6 +29,18 @@ len[s]``:
     so every skipped cell repeats the previous block index and the
     pipeline issues no fetch for it.
 
+Window layers skip from below too.  ``lo[s]``
+(``PagedAttentionKV.lo``, 0 when absent) is the first position slot
+``s`` may see, ``max(pos - window + 1, 0)`` on a sliding-window layer:
+a cell is live only when it also ends past ``lo[s]``, the index maps
+clamp the window up to the first page holding ``lo[s]`` (the cells
+below it repeat that page's index and fetch it once), and keys below
+``lo[s]`` are masked like those past ``len``.  With ``lo = 0`` every
+condition reads as before.  A view without ``lo`` (every layer of a model
+with no windows) runs the kernel without the lower bound at all, the
+same kernel as before windows existed; one with ``lo`` (every layer of a
+model with windows, full ones at ``lo = 0``) gives the same bits.
+
 A skipped cell contributed exactly nothing before (its keys are all
 masked: ``p = 0`` and ``corr = 1``), so skipping it changes no bit of
 a live slot's ``(acc, m, l)``; pages past a slot's last live page are
@@ -62,7 +74,7 @@ does not read yet — the scheduler falls back to the gather path there).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -86,11 +98,14 @@ class PagedAttentionKV(NamedTuple):
       page_table       : (S, W) int32   pool page id per slot x window
       pos              : (S,) int32     live context length per slot
                                         (0 for a dead slot)
+      lo               : (S,) int32     first visible position per slot
+                                        (window layers; None = 0)
     """
     k_pages: jax.Array
     v_pages: jax.Array
     page_table: jax.Array
     pos: jax.Array
+    lo: Optional[jax.Array] = None
 
     @property
     def shape(self) -> tuple:
@@ -118,15 +133,24 @@ def _dims(q, kv) -> tuple:
     return s, kvh, rep, hd, num_pages, ps, w
 
 
-def _fused_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
-                  acc_ref, m_ref, l_ref, m_s, l_s, acc_s, *,
-                  page_size: int, last_w: int):
+def _fused_kernel(*refs, page_size: int, last_w: int, windowed: bool):
     """One grid step = one (slot, page-window) cell.  ``k_ref``/``v_ref``
     hold page ``page_table[s, w]`` (the index map did the routing); the
     VMEM scratch carries the online-softmax state across the w axis.
-    Cells at or past the slot's live length ``len_ref[s]`` are skipped."""
+    Cells at or past the slot's live length ``len_ref[s]``, and cells
+    ending at or before its first visible position ``lo_ref[s]``, are
+    skipped."""
+    if windowed:
+        (pt_ref, len_ref, lo_ref, q_ref, k_ref, v_ref, acc_ref, m_ref,
+         l_ref, m_s, l_s, acc_s) = refs
+    else:
+        (pt_ref, len_ref, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, m_s,
+         l_s, acc_s) = refs
     s = pl.program_id(0)
     w = pl.program_id(1)
+    live = w * page_size < len_ref[s]
+    if windowed:
+        live &= (w + 1) * page_size > lo_ref[s]
 
     @pl.when(w == 0)
     def _init():
@@ -134,7 +158,7 @@ def _fused_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
         l_s[...] = jnp.zeros(l_s.shape, l_s.dtype)
         acc_s[...] = jnp.zeros(acc_s.shape, acc_s.dtype)
 
-    @pl.when(w * page_size < len_ref[s])
+    @pl.when(live)
     def _cell():
         q = q_ref[0]                                    # (KV, rep, hd)
         k = k_ref[0]                                    # (ps, KV, hd)
@@ -150,7 +174,10 @@ def _fused_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
                         preferred_element_type=jnp.float32)
         kpos = w * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, page_size), 2)
-        sc = jnp.where(kpos < len_ref[s], sc, NEG_INF)
+        visible = kpos < len_ref[s]
+        if windowed:
+            visible &= kpos >= lo_ref[s]
+        sc = jnp.where(visible, sc, NEG_INF)
         m_prev = m_s[...]
         m_new = jnp.maximum(m_prev, sc.max(axis=-1))
         p = jnp.exp(sc - m_new[..., None])
@@ -168,12 +195,16 @@ def _fused_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
         l_ref[0] = l_s[...]
 
 
-def _live_page(pt, lens, i, j, page_size: int):
+def _live_page(i, j, pt, lens, *los, page_size: int):
     """The pool page cell ``(i, j)`` reads: window ``j`` clamped to slot
-    ``i``'s last live page, so a skipped cell repeats the block index
-    before it and is not fetched."""
+    ``i``'s live pages (from the one holding ``lo``, when given, to the
+    last live one), so a skipped cell repeats a neighbour's block index
+    and is not fetched."""
     last = jnp.maximum(lens[i] - 1, 0) // page_size
-    return pt[i, jnp.minimum(j, last)]
+    if not los:
+        return pt[i, jnp.minimum(j, last)]
+    first = jnp.minimum(los[0][i] // page_size, last)
+    return pt[i, jnp.clip(j, first, last)]
 
 
 def paged_attention(q, kv: PagedAttentionKV, *,
@@ -185,26 +216,23 @@ def paged_attention(q, kv: PagedAttentionKV, *,
     m = -1e30, l = 0``."""
     s, kvh, rep, hd, num_pages, ps, w = _dims(q, kv)
     page = functools.partial(_live_page, page_size=ps)
+    # (page_table, len) and, on a window layer, lo
+    scalars = (kv.page_table, kv.pos) + (
+        () if kv.lo is None else (kv.lo.astype(kv.pos.dtype),))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,              # (page_table, len)
+        num_scalar_prefetch=len(scalars),
         grid=(s, w),
         in_specs=[
-            pl.BlockSpec((1, kvh, rep, hd),
-                         lambda i, j, pt, lens: (i, 0, 0, 0)),
+            pl.BlockSpec((1, kvh, rep, hd), lambda i, j, *_: (i, 0, 0, 0)),
             pl.BlockSpec((1, ps, kvh, hd),
-                         lambda i, j, pt, lens: (page(pt, lens, i, j),
-                                                 0, 0, 0)),
+                         lambda i, j, *sc: (page(i, j, *sc), 0, 0, 0)),
             pl.BlockSpec((1, ps, kvh, hd),
-                         lambda i, j, pt, lens: (page(pt, lens, i, j),
-                                                 0, 0, 0)),
+                         lambda i, j, *sc: (page(i, j, *sc), 0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, kvh, rep, hd),
-                         lambda i, j, pt, lens: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kvh, rep),
-                         lambda i, j, pt, lens: (i, 0, 0)),
-            pl.BlockSpec((1, kvh, rep),
-                         lambda i, j, pt, lens: (i, 0, 0)),
+            pl.BlockSpec((1, kvh, rep, hd), lambda i, j, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((1, kvh, rep), lambda i, j, *_: (i, 0, 0)),
+            pl.BlockSpec((1, kvh, rep), lambda i, j, *_: (i, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((kvh, rep), jnp.float32),
@@ -213,7 +241,8 @@ def paged_attention(q, kv: PagedAttentionKV, *,
         ],
     )
     fn = pl.pallas_call(
-        functools.partial(_fused_kernel, page_size=ps, last_w=w - 1),
+        functools.partial(_fused_kernel, page_size=ps, last_w=w - 1,
+                          windowed=kv.lo is not None),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((s, kvh, rep, hd), jnp.float32),
@@ -223,8 +252,13 @@ def paged_attention(q, kv: PagedAttentionKV, *,
         interpret=interpret,
         name="paged_attention",
     )
-    acc, m, l = fn(kv.page_table, kv.pos, q, kv.k_pages, kv.v_pages)
+    acc, m, l = fn(*scalars, q, kv.k_pages, kv.v_pages)
     return acc, m, l
+
+
+def _lo(kv: PagedAttentionKV) -> jax.Array:
+    return (jnp.zeros_like(kv.pos) if kv.lo is None
+            else kv.lo.astype(kv.pos.dtype))
 
 
 def paged_attention_ref(q, kv: PagedAttentionKV) -> tuple:
@@ -240,7 +274,8 @@ def paged_attention_ref(q, kv: PagedAttentionKV) -> tuple:
     q32 = q.astype(jnp.float32)
     sc = jnp.einsum("skrd,stkd->skrt", q32, kg.astype(jnp.float32),
                     preferred_element_type=jnp.float32)
-    valid = jnp.arange(w * ps, dtype=jnp.int32)[None, :] < kv.pos[:, None]
+    kpos = jnp.arange(w * ps, dtype=jnp.int32)[None, :]
+    valid = (kpos < kv.pos[:, None]) & (kpos >= _lo(kv)[:, None])
     sc = jnp.where(valid[:, None, None, :], sc, NEG_INF)
     m = sc.max(axis=-1)
     # masked keys weigh exactly 0 (as exp(-1e30 - m) does once a live

@@ -32,7 +32,7 @@ import dataclasses
 import functools
 from typing import Any, Callable, Optional
 
-OPS = ("ternary", "cim", "attention")
+OPS = ("ternary", "cim", "attention", "ternary_grouped")
 DOMAINS = ("float", "int8")
 PACKINGS = ("base3", "trit2")
 PHASES = ("auto", "decode", "prefill")
@@ -80,7 +80,7 @@ class ExecutionPlan:
     under a ``device`` request.  ``adc_bits`` / ``num_trits`` are set
     for the macro-exact ``cim`` op and for device-fidelity plans.
     """
-    op: str                                  # ternary | cim | attention
+    op: str                                  # ternary | cim | attention | ternary_grouped
     backend: str                             # resolved name (never 'auto')
     domain: str                              # float | int8
     packing: str                             # base3 | trit2

@@ -4,6 +4,11 @@ Layout: q (B,S,H,hd), kv (B,T,KV,hd).  GQA is computed with grouped
 einsums (no materialized head repetition).  Decode updates a KV cache via
 dynamic_update_slice; sliding-window decode uses a rolling buffer of size
 `window` so the long_500k cell stays O(window) in memory for SWA archs.
+
+Stacks that mix window and full layers (``cfg.layer_windows``) pass each
+layer's ``LayerAttn`` (window, RoPE table, RoPE scale) through the layer
+scan: the window is a mask over a cache that holds every position (and,
+in the fused paged read, a per-slot lower bound of the pages visited).
 """
 from __future__ import annotations
 
@@ -14,6 +19,27 @@ import jax.numpy as jnp
 
 from .config import ModelConfig
 from .layers import ATTN_OUT, apply_rope, dense, rms_norm
+
+
+class LayerAttn(NamedTuple):
+    """One layer's attention kind inside a layer scan (traced values):
+    ``window`` () int32, 0 = full; ``inv_freq`` (hd/2,) f32 RoPE
+    frequencies; ``rope_scale`` () f32 on cos and sin (YaRN)."""
+    window: jax.Array
+    inv_freq: jax.Array
+    rope_scale: jax.Array
+
+
+def _window_of(cfg: ModelConfig, layer: Optional[LayerAttn]):
+    return cfg.sliding_window if layer is None else layer.window
+
+
+def _in_window(kpos, qpos, window):
+    """Keys inside a window of ``window`` positions ending at the query
+    (a Python int, or a traced scalar where 0 means no window)."""
+    if isinstance(window, int):
+        return kpos > (qpos - window) if window > 0 else True
+    return (window <= 0) | (kpos > (qpos - window))
 
 
 class KVCache(NamedTuple):
@@ -54,7 +80,8 @@ def init_cache(batch: int, capacity: int, kv_heads: int, hd: int,
 
 
 def _project_qkv(x, w, cfg: ModelConfig, x_kv=None, positions=None,
-                 rope: bool = True, cim_cfg=None):
+                 rope: bool = True, cim_cfg=None,
+                 layer: Optional[LayerAttn] = None):
     b, s, d = x.shape
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     src = x if x_kv is None else x_kv
@@ -65,9 +92,11 @@ def _project_qkv(x, w, cfg: ModelConfig, x_kv=None, positions=None,
         q = rms_norm(q, w["q_norm"], cfg.norm_eps)
         k = rms_norm(k, w["k_norm"], cfg.norm_eps)
     if rope and positions is not None:
-        q = apply_rope(q, positions, cfg.rope_theta)
+        table = (() if layer is None
+                 else (layer.inv_freq, layer.rope_scale))
+        q = apply_rope(q, positions, cfg.rope_theta, *table)
         kpos = positions if x_kv is None else jnp.arange(src.shape[1])
-        k = apply_rope(k, kpos, cfg.rope_theta)
+        k = apply_rope(k, kpos, cfg.rope_theta, *table)
     return q, k, v
 
 
@@ -88,7 +117,8 @@ def _gqa_attend(q, k, v, mask, cfg: ModelConfig):
 
 
 def flash_attention(q, k, v, cfg: ModelConfig, causal: bool = True,
-                    q_offset=0, chunk: int = 512) -> jax.Array:
+                    q_offset=0, chunk: int = 512,
+                    window=None) -> jax.Array:
     """Memory-bounded attention: lax.scan over KV chunks with running
     (max, denom, acc) — the flash-attention recurrence in pure jnp.  Never
     materializes the (S, T) score matrix; per-step footprint is
@@ -109,7 +139,7 @@ def flash_attention(q, k, v, cfg: ModelConfig, causal: bool = True,
     t = k.shape[1]
     kv = k.shape[2]
     rep = h // kv
-    window = cfg.sliding_window
+    window = cfg.sliding_window if window is None else window
     if t % chunk:
         pad = -t % chunk
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -128,8 +158,7 @@ def flash_attention(q, k, v, cfg: ModelConfig, causal: bool = True,
         kpos = (c0 + jnp.arange(chunk))[None, :]         # (1,chunk)
         ok = kpos <= qpos if causal else (kpos < t)
         ok &= kpos < t                                   # mask padding
-        if window:
-            ok &= kpos > (qpos - window)
+        ok &= _in_window(kpos, qpos, window)
         sc = jnp.where(ok[None, :, None, None, :], sc, -1e30)
         m_new = jnp.maximum(m, sc.max(axis=-1))
         p = jnp.exp(sc - m_new[..., None])
@@ -167,9 +196,12 @@ def _constrain_qkv(q, k, v):
     return q, k, v
 
 
-def attend(q, k, v, cfg: ModelConfig, causal: bool = True, q_offset=0):
+def attend(q, k, v, cfg: ModelConfig, causal: bool = True, q_offset=0,
+           window=None):
     """Dispatch: direct masked attention for short sequences, flash
-    above.  ``k``/``v`` may be ``paged_kv.PagedKV`` gather-views — the
+    above.  ``window`` (default ``cfg.sliding_window``) may be a traced
+    per-layer scalar.
+    ``k``/``v`` may be ``paged_kv.PagedKV`` gather-views — the
     paged layout gathers into the dense (B, T, KV, hd) operand here, so
     both branches (and their outputs) are identical to dense K/V."""
     from . import paged_kv
@@ -177,31 +209,35 @@ def attend(q, k, v, cfg: ModelConfig, causal: bool = True, q_offset=0):
     v = paged_kv.materialize(v)
     q, k, v = _constrain_qkv(q, k, v)
     s, t = q.shape[1], k.shape[1]
+    window = cfg.sliding_window if window is None else window
     if max(s, t) <= FLASH_THRESHOLD:
         off = q_offset if s != t else 0
-        mask = causal_mask(s, t, cfg.sliding_window, off) if causal else None
+        mask = causal_mask(s, t, window, off) if causal else None
         return _gqa_attend(q, k, v, mask, cfg)
-    return flash_attention(q, k, v, cfg, causal=causal, q_offset=q_offset)
+    return flash_attention(q, k, v, cfg, causal=causal, q_offset=q_offset,
+                           window=window)
 
 
-def causal_mask(s: int, t: int, window: int = 0, offset: int = 0) -> jax.Array:
-    """Additive (1,1,1,s,t) mask; offset = absolute position of query 0."""
+def causal_mask(s: int, t: int, window=0, offset: int = 0) -> jax.Array:
+    """Additive (1,1,1,s,t) mask; offset = absolute position of query 0;
+    ``window`` an int or a traced scalar (0 = none)."""
     qpos = jnp.arange(s)[:, None] + offset
     kpos = jnp.arange(t)[None, :]
-    ok = kpos <= qpos
-    if window > 0:
-        ok &= kpos > (qpos - window)
+    ok = (kpos <= qpos) & _in_window(kpos, qpos, window)
     return jnp.where(ok, 0.0, -1e30)[None, None, None]
 
 
 def self_attention(x, w, cfg: ModelConfig, positions=None, causal=True,
-                   cim_cfg=None) -> jax.Array:
+                   cim_cfg=None, layer: Optional[LayerAttn] = None
+                   ) -> jax.Array:
     """Training/prefill self-attention (full or sliding-window)."""
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)
-    q, k, v = _project_qkv(x, w, cfg, positions=positions, cim_cfg=cim_cfg)
-    out = attend(q, k, v, cfg, causal=causal)
+    q, k, v = _project_qkv(x, w, cfg, positions=positions, cim_cfg=cim_cfg,
+                           layer=layer)
+    out = attend(q, k, v, cfg, causal=causal,
+                 window=_window_of(cfg, layer))
     return dense(out, w["wo"], cim_cfg, x_axes=ATTN_OUT)
 
 
@@ -212,12 +248,14 @@ def cross_attention(x, x_kv, w, cfg: ModelConfig, cim_cfg=None) -> jax.Array:
 
 
 def prefill_attention(x, w, cfg: ModelConfig, cache: KVCache,
-                      cim_cfg=None) -> tuple[jax.Array, KVCache]:
+                      cim_cfg=None, layer: Optional[LayerAttn] = None
+                      ) -> tuple[jax.Array, KVCache]:
     """Prefill: run causal attention AND populate the cache."""
     b, s, _ = x.shape
     positions = jnp.arange(s)
-    q, k, v = _project_qkv(x, w, cfg, positions=positions, cim_cfg=cim_cfg)
-    out = attend(q, k, v, cfg, causal=True)
+    q, k, v = _project_qkv(x, w, cfg, positions=positions, cim_cfg=cim_cfg,
+                           layer=layer)
+    out = attend(q, k, v, cfg, causal=True, window=_window_of(cfg, layer))
     cap = cache.k.shape[1]
     if cfg.sliding_window and cap == cfg.sliding_window:
         keep = min(s, cap)
@@ -241,7 +279,7 @@ def prefill_attention(x, w, cfg: ModelConfig, cache: KVCache,
 
 
 def decode_attention_read(x, w, cfg: ModelConfig, cache: KVCache,
-                          cim_cfg=None):
+                          cim_cfg=None, layer: Optional[LayerAttn] = None):
     """One-token decode that does NOT write the cache: attends over the
     (stale) cache slice + the freshly projected k/v of the current token,
     and returns them for a single model-level cache update.
@@ -258,7 +296,7 @@ def decode_attention_read(x, w, cfg: ModelConfig, cache: KVCache,
     assert s == 1, "decode_attention is single-token"
     pos = cache.index
     q, k, v = _project_qkv(x, w, cfg, positions=pos[None, None],
-                           cim_cfg=cim_cfg)
+                           cim_cfg=cim_cfg, layer=layer)
     cap = cache.k.shape[1]
     rolling = cfg.sliding_window and cap == cfg.sliding_window
     slot = pos % cap if rolling else pos
@@ -269,6 +307,8 @@ def decode_attention_read(x, w, cfg: ModelConfig, cache: KVCache,
         valid = (slots < jnp.minimum(pos, cap)) & (slots != slot)
     else:
         valid = slots < pos
+        if layer is not None:
+            valid &= _in_window(slots, pos, layer.window)
     q, ck, cv = _constrain_qkv(q, cache.k, cache.v)
     # NO concatenation: a (cap+1)-long axis breaks the cache's sequence
     # sharding (measured: it all-gathers the whole cache).  Instead merge
@@ -317,7 +357,8 @@ def decode_attention_read(x, w, cfg: ModelConfig, cache: KVCache,
 
 
 def paged_decode_attention_read(x, w, cfg: ModelConfig, k_pages, v_pages,
-                                page_table, pos, plan, cim_cfg=None):
+                                page_table, pos, plan, cim_cfg=None,
+                                layer: Optional[LayerAttn] = None):
     """Batched one-token decode read straight off one layer's page pool:
     the planned ``attention`` executor (``kernels.paged_attention``)
     consumes the page table in-kernel, so the gathered dense KV copy the
@@ -329,19 +370,28 @@ def paged_decode_attention_read(x, w, cfg: ModelConfig, k_pages, v_pages,
 
     ``x`` is (S, 1, d) — all S slots at once, not vmapped: the executor
     runs one grid over every (slot, page) cell.  Returns
-    (out (S, 1, d), k_new (S, KV, hd), v_new)."""
+    (out (S, 1, d), k_new (S, KV, hd), v_new).
+
+    A window layer (``layer.window > 0``) gives each slot the first
+    position it may see, ``lo = max(pos - window + 1, 0)``: the kernel
+    skips the pages wholly below it and masks the keys under it."""
     from repro.kernels import execute
     from repro.kernels.paged_attention import PagedAttentionKV
     s_dim, s1, _ = x.shape
     assert s1 == 1, "paged decode read is single-token"
     q, k, v = _project_qkv(x, w, cfg, positions=pos[:, None],
-                           cim_cfg=cim_cfg)
+                           cim_cfg=cim_cfg, layer=layer)
+    lo = None
+    if layer is not None:
+        lo = jnp.where(layer.window > 0,
+                       jnp.maximum(pos - layer.window + 1, 0),
+                       0).astype(jnp.int32)
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     rep = h // kvh
     qg = (q / jnp.sqrt(jnp.asarray(hd, q.dtype))).reshape(
         s_dim, kvh, rep, hd)
     acc_c, m_c, l_c = execute(
-        plan, qg, PagedAttentionKV(k_pages, v_pages, page_table, pos))
+        plan, qg, PagedAttentionKV(k_pages, v_pages, page_table, pos, lo))
     # new-token block, then the flash two-block merge (the dense decode
     # read's rule verbatim): slots with no live context come back with
     # m_c = -1e30, l_c = 0 and renormalize onto the fresh token alone

@@ -32,6 +32,15 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     qk_norm: bool = False
     sliding_window: int = 0        # 0 = full attention
+    # per-layer attention kinds (mixed window/full stacks): layer l's
+    # window, 0 = full attention; empty -> every layer ``sliding_window``.
+    # Paged and dense caches hold every position for these layers (no
+    # rolling buffer); the window is a mask and a skip in the kernel.
+    layer_windows: tuple = ()
+    # YaRN RoPE of the full-attention layers of a ``layer_windows`` stack:
+    # (factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # attention_factor); window layers keep plain RoPE at rope_theta
+    rope_yarn: tuple = ()
     # MoE
     num_experts: int = 0
     experts_per_token: int = 0
@@ -56,6 +65,12 @@ class ModelConfig:
     @property
     def sub_quadratic(self) -> bool:
         return self.family in ("ssm", "hybrid") or self.sliding_window > 0
+
+    @property
+    def per_layer_attn(self) -> bool:
+        """Whether the layer scans carry each layer's window and RoPE
+        table (``layers.layer_attn_tables``)."""
+        return bool(self.layer_windows or self.rope_yarn)
 
     @property
     def hd(self) -> int:

@@ -59,15 +59,71 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, hd); positions: (B, S) or (S,)."""
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """YaRN's blended inverse frequencies (arXiv:2309.00071), as the
+    published ``rope_type: yarn`` computes them: dimensions that turn
+    more than ``beta_fast`` times over ``original_max`` positions keep
+    their frequency, those under ``beta_slow`` turns are divided by
+    ``factor``, with a linear ramp between (bounds floored / ceiled).
+    Returns a float32 numpy array of ``head_dim / 2``."""
+    import numpy as np
+    dim = head_dim
+
+    def corr_dim(rot):
+        return (dim * math.log(original_max / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos = theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    extra, inter = 1.0 / pos, 1.0 / (factor * pos)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp               # weight of the unscaled frequency
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def layer_attn_tables(cfg: ModelConfig):
+    """Per-layer (window (L,) int32, inv_freq (L, hd/2) f32, rope scale
+    (L,) f32) of a ``per_layer_attn`` config: window layers plain RoPE
+    at ``rope_theta``, full layers YaRN when ``rope_yarn`` is set."""
+    import numpy as np
+    n = cfg.num_layers
+    windows = tuple(cfg.layer_windows) or (cfg.sliding_window,) * n
+    plain = 1.0 / (cfg.rope_theta ** (np.arange(0, cfg.hd, 2,
+                                               dtype=np.float32) / cfg.hd))
+    full, full_scale = plain, 1.0
+    if cfg.rope_yarn:
+        factor, orig, fast, slow, att = cfg.rope_yarn
+        full = yarn_inv_freq(cfg.hd, cfg.rope_theta, factor, orig, fast,
+                             slow)
+        full_scale = att
+    inv = np.stack([plain if w else full for w in windows])
+    scale = np.asarray([1.0 if w else full_scale for w in windows],
+                       np.float32)
+    return np.asarray(windows, np.int32), inv.astype(np.float32), scale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               inv_freq: Optional[jax.Array] = None,
+               scale=None) -> jax.Array:
+    """x: (B, S, H, hd); positions: (B, S) or (S,).  ``inv_freq``
+    (hd/2,) replaces the plain frequencies at ``theta`` and ``scale``
+    multiplies cos and sin (YaRN's attention factor)."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)                 # (hd/2,)
+    freqs = (rope_frequencies(hd, theta) if inv_freq is None
+             else inv_freq)                             # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     if angles.ndim == 2:                                # (S, hd/2) -> (1,S,..)
         angles = angles[None]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

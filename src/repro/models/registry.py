@@ -115,13 +115,13 @@ class BaseModel:
             f"serve it from the dense slot pool")
 
     def decode_paged_fused(self, params, tokens, pool, page_table, pos,
-                           cim=None, attn_plan=None):
+                           cim=None, attn_plan=None, live=None):
         """Batched one-token decode consuming the page pool in place
         through a planned ``op='attention'`` executor — no gathered
         dense copy.  Same read-only contract as :meth:`decode_paged`,
         but over ALL slots at once: ``tokens (S,)``, ``page_table
         (S, W)``, ``pos (S,)``; returns (logits (S, 1, V), kts
-        (L, S, KV, hd), vts)."""
+        (L, S, KV, hd), vts, moe_stats (2,) int32)."""
         raise NotImplementedError(
             f"fused paged decode is not implemented for "
             f"{type(self).__name__} (family {self.cfg.family!r}); "
@@ -190,15 +190,37 @@ class TransformerLM(BaseModel):
 
     # ----- shared layer bodies ----------------------------------------
     def _mlp(self, x, wl, cim):
-        cfg = self.cfg
-        if cfg.num_experts:
-            return moe_mod.moe_block(x, wl, cfg, cim)
-        return swiglu(x, wl["w1"], wl["w3"], wl["w2"], cim), 0.0
+        return self._ffn(x, wl, cim)[:2]
 
-    def _self_block(self, x, wl, cim, positions=None):
+    def _ffn(self, x, wl, cim, live=None):
+        """The block's MLP: ``(y, aux_loss, moe_stats)``.  Packed experts
+        under an exact int8 plan serve dropless through the grouped
+        kernel (``moe.moe_serve``; ``live`` (B,) masks rows out of the
+        routing, ``moe_stats`` = [routed (row, expert) pairs, experts
+        with a row]); float experts keep GShard's capacity dispatch."""
+        cfg = self.cfg
+        none = jnp.zeros((2,), jnp.int32)
+        if cfg.num_experts:
+            if moe_mod.serves_dropless(wl, cim):
+                y, stats = moe_mod.moe_serve(x, wl, cfg, cim, live)
+                return y, 0.0, stats
+            return moe_mod.moe_block(x, wl, cfg, cim) + (none,)
+        return swiglu(x, wl["w1"], wl["w3"], wl["w2"], cim), 0.0, none
+
+    def _layer_attn(self):
+        """Each layer's ``attn.LayerAttn`` stacked over layers, scanned
+        beside the blocks; None for a stack of one attention kind."""
+        if not self.cfg.per_layer_attn:
+            return None
+        win, inv, scale = layers_mod.layer_attn_tables(self.cfg)
+        return attn.LayerAttn(jnp.asarray(win), jnp.asarray(inv),
+                              jnp.asarray(scale))
+
+    def _self_block(self, x, wl, cim, positions=None, layer=None):
         cfg = self.cfg
         h = attn.self_attention(rms_norm(x, wl["ln1"], cfg.norm_eps), wl, cfg,
-                                positions=positions, cim_cfg=cim)
+                                positions=positions, cim_cfg=cim,
+                                layer=layer)
         x = x + h
         m, aux = self._mlp(rms_norm(x, wl["ln2"], cfg.norm_eps), wl, cim)
         return x + m, aux
@@ -217,12 +239,14 @@ class TransformerLM(BaseModel):
         if cfg.cross_attn_every:
             x = self._forward_vlm(x, params, batch, cim)
         else:
-            def body(carry, wl):
+            def body(carry, inp):
                 x, aux = carry
-                x, a = self._self_block(x, wl, cim)
+                wl, lat = inp
+                x, a = self._self_block(x, wl, cim, layer=lat)
                 return (x, aux + a), None
             (x, aux), _ = jax.lax.scan(_maybe_remat(body, cfg), (x, aux),
-                                       params["blocks"])
+                                       (params["blocks"],
+                                        self._layer_attn()))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = dense(x, params["unembed"], cim)
         return (logits, aux) if return_aux else logits
@@ -278,12 +302,13 @@ class TransformerLM(BaseModel):
         cfg = self.cfg
         if not cfg.cross_attn_every:
             def body(x, inp):
-                wl, k_l, v_l = inp
+                wl, k_l, v_l, lat = inp
                 cache = attn.KVCache(k_l, v_l, state["pos"])
-                x, newc = step_fn(x, wl, cache, None, cim)
+                x, newc = step_fn(x, wl, cache, lat, cim)
                 return x, (newc.k, newc.v)
             x, (ks, vs) = jax.lax.scan(
-                body, x, (params["blocks"], state["k"], state["v"]))
+                body, x, (params["blocks"], state["k"], state["v"],
+                          self._layer_attn()))
             return x, ks, vs
         k = cfg.cross_attn_every
         ng = cfg.num_layers // k
@@ -342,9 +367,10 @@ class TransformerLM(BaseModel):
         x = _take_embed(params["embed"], tokens).astype(cfg.dtype)
         state["pos"] = jnp.zeros((), jnp.int32)
 
-        def step(x, wl, cache, _, cim):
+        def step(x, wl, cache, lat, cim):
             xa = rms_norm(x, wl["ln1"], cfg.norm_eps)
-            out, newc = attn.prefill_attention(xa, wl, cfg, cache, cim)
+            out, newc = attn.prefill_attention(xa, wl, cfg, cache, cim,
+                                               layer=lat)
             x = x + out
             m, _ = self._mlp(rms_norm(x, wl["ln2"], cfg.norm_eps), wl, cim)
             return x + m, newc
@@ -375,19 +401,19 @@ class TransformerLM(BaseModel):
 
         def body(x, inp):
             if int8_kv:
-                wl, k_l, v_l, ks_l, vs_l = inp
+                wl, lat, k_l, v_l, ks_l, vs_l = inp
                 cache = attn.KVCache(k_l, v_l, state["pos"], ks_l, vs_l)
             else:
-                wl, k_l, v_l = inp
+                wl, lat, k_l, v_l = inp
                 cache = attn.KVCache(k_l, v_l, state["pos"])
             xa = rms_norm(x, wl["ln1"], cfg.norm_eps)
             out, kt, vt = attn.decode_attention_read(xa, wl, cfg, cache,
-                                                     cim)
+                                                     cim, layer=lat)
             x = x + out
             m, _ = self._mlp(rms_norm(x, wl["ln2"], cfg.norm_eps), wl, cim)
             return x + m, (kt, vt)
 
-        xs = (params["blocks"], state["k"], state["v"])
+        xs = (params["blocks"], self._layer_attn(), state["k"], state["v"])
         if int8_kv:
             xs = xs + (state["k_scale"], state["v_scale"])
         x, (kts, vts) = jax.lax.scan(body, x, xs)
@@ -396,10 +422,13 @@ class TransformerLM(BaseModel):
     @property
     def supports_paged_kv(self) -> bool:
         # the vlm grouped path fuses its cache write into the layer
-        # scan, and sliding-window models decode against a ROLLING
-        # cache (slot = pos % window, engaged only when cap == window)
-        # that a page-gathered view's capacity would silently disarm;
-        # only the plain full-cache read-then-write decode pages cleanly
+        # scan, and a model-wide ``sliding_window`` decodes against a
+        # ROLLING cache (slot = pos % window, engaged only when cap ==
+        # window) that a page-gathered view's capacity would silently
+        # disarm.  Per-layer windows (``layer_windows``) keep every
+        # position and mask (or, fused, skip) per layer, so they page
+        # like the plain full-cache read-then-write decode; a page
+        # still holds every layer's KV, so window layers free no pages
         return not self.cfg.cross_attn_every and \
             not self.cfg.sliding_window
 
@@ -423,14 +452,17 @@ class TransformerLM(BaseModel):
         return dense(x, params["unembed"], cim), kts, vts
 
     def decode_paged_fused(self, params, tokens, pool, page_table, pos,
-                           cim=None, attn_plan=None):
+                           cim=None, attn_plan=None, live=None):
         """Batched one-token decode straight off the page pool: the
         layer scan carries each layer's raw page arrays and the planned
         attention executor (``attn_plan``, an ``op='attention'``
         ExecutionPlan) reads them through the page table in-kernel —
         the ``slot_view`` gather copy is never materialized.  Returns
-        (logits (S, 1, V), kts (L, S, KV, hd), vts) in compute dtype;
-        the scheduler's page scatter is unchanged."""
+        (logits (S, 1, V), kts (L, S, KV, hd), vts, moe_stats (2,)) in
+        compute dtype; the scheduler's page scatter is unchanged.
+        ``live`` (S,) keeps dead slots out of a dropless MoE's routing;
+        ``moe_stats`` are its counts summed over layers (``_ffn``),
+        zeros for a dense model."""
         if not self.supports_paged_kv:
             return super().decode_paged_fused(params, tokens, pool,
                                               page_table, pos, cim,
@@ -445,19 +477,21 @@ class TransformerLM(BaseModel):
         x = _take_embed(params["embed"], tokens[:, None]).astype(cfg.dtype)
 
         def body(x, inp):
-            wl, k_l, v_l = inp
+            wl, lat, k_l, v_l = inp
             xa = rms_norm(x, wl["ln1"], cfg.norm_eps)
             out, kt, vt = attn.paged_decode_attention_read(
-                xa, wl, cfg, k_l, v_l, page_table, pos, attn_plan, cim)
+                xa, wl, cfg, k_l, v_l, page_table, pos, attn_plan, cim,
+                layer=lat)
             x = x + out
-            m, _ = self._mlp(rms_norm(x, wl["ln2"], cfg.norm_eps), wl,
-                             cim)
-            return x + m, (kt, vt)
+            m, _, st = self._ffn(rms_norm(x, wl["ln2"], cfg.norm_eps), wl,
+                                 cim, live)
+            return x + m, (kt, vt, st)
 
-        x, (kts, vts) = jax.lax.scan(body, x, (params["blocks"],
-                                               k_pages, v_pages))
+        x, (kts, vts, sts) = jax.lax.scan(
+            body, x, (params["blocks"], self._layer_attn(), k_pages,
+                      v_pages))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return dense(x, params["unembed"], cim), kts, vts
+        return dense(x, params["unembed"], cim), kts, vts, sts.sum(0)
 
     def decode(self, params, token, state, cim=None):
         cfg = self.cfg

@@ -286,20 +286,27 @@ def make_paged_decode_loop(model, chunk: int, cim=None, spmd_axes=None,
     argmax (tests/test_paged.py pins fused == gather == dense).  Dead
     slots read with length 0 there, so the kernel visits none of their
     pages (kernels/paged_attention.py).
+
+    A mixture-of-experts model on the fused read routes only its live
+    slots, and the loop appends ``moe (2,) int32`` to its outputs: the
+    chunk's routed (row, expert) pairs and experts-with-a-row, summed
+    over layers and steps (``PagedScheduler.moe_rows_routed`` /
+    ``moe_experts_read``), carried on the chunk's one transfer.
     """
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")
     from repro.models import paged_kv
+    moe = attn_plan is not None and bool(model.cfg.num_experts)
 
     if attn_plan is not None:
-        def vread(params, pool, tok, page_table, pos):
-            logits, kts, vts = model.decode_paged_fused(
+        def vread(params, pool, tok, page_table, pos, live):
+            logits, kts, vts, st = model.decode_paged_fused(
                 params, tok, pool, page_table, pos, cim=cim,
-                attn_plan=attn_plan)
+                attn_plan=attn_plan, live=live)
             # logits (S, 1, V) -> (S,); kts (L, S, KV, hd) ->
             # (S, L, KV, hd), the append_tokens scatter layout
             return (greedy_sample(logits), jnp.moveaxis(kts, 0, 1),
-                    jnp.moveaxis(vts, 0, 1))
+                    jnp.moveaxis(vts, 0, 1), st)
     else:
         def read_one(params, pool, tok, pt_row, pos):
             logits, kt, vt = model.decode_paged(params, tok[None, None],
@@ -307,8 +314,12 @@ def make_paged_decode_loop(model, chunk: int, cim=None, spmd_axes=None,
                                                 cim=cim)
             return greedy_sample(logits)[0], kt[:, 0, 0], vt[:, 0, 0]
 
-        vread = jax.vmap(read_one, in_axes=(None, None, 0, 0, 0),
-                         spmd_axis_name=spmd_axes)
+        vmapped = jax.vmap(read_one, in_axes=(None, None, 0, 0, 0),
+                           spmd_axis_name=spmd_axes)
+
+        def vread(params, pool, tok, page_table, pos, live):
+            return vmapped(params, pool, tok, page_table, pos) + (
+                jnp.zeros((2,), jnp.int32),)
 
     def chunk_step(params, tok, pool, page_table, pos, live, made, fresh,
                    max_new_row, eos_row):
@@ -323,13 +334,14 @@ def make_paged_decode_loop(model, chunk: int, cim=None, spmd_axes=None,
             return jnp.any(live) & (step < chunk)
 
         def body(carry):
-            step, tok, pool, pos, live, buf, cnt, made, occ = carry
+            step, tok, pool, pos, live, buf, cnt, made, occ, st = carry
             occ = occ + jnp.sum(live.astype(jnp.int32))
             # the fused read skips every cell of a dead slot, given as
             # no context; live slots read (and RoPE) their own position
             read_pos = pos if attn_plan is None else jnp.where(live, pos, 0)
-            tok_new, kts, vts = vread(params, pool, tok, page_table,
-                                      read_pos)
+            tok_new, kts, vts, st_step = vread(params, pool, tok,
+                                               page_table, read_pos, live)
+            st = st + st_step
             pool = paged_kv.append_tokens(pool, kts, vts, page_table,
                                           pos, live)
             tok = tok_new
@@ -339,14 +351,15 @@ def make_paged_decode_loop(model, chunk: int, cim=None, spmd_axes=None,
             cnt = cnt + live.astype(jnp.int32)
             made = made + live.astype(jnp.int32)
             live = live & (made < max_new_row) & (tok != eos_row)
-            return step + 1, tok, pool, pos, live, buf, cnt, made, occ
+            return step + 1, tok, pool, pos, live, buf, cnt, made, occ, st
 
         zero = jnp.zeros((), jnp.int32)
-        (steps, tok, pool, pos, live, buf, cnt, made,
-         occ) = jax.lax.while_loop(
+        (steps, tok, pool, pos, live, buf, cnt, made, occ,
+         st) = jax.lax.while_loop(
             cond, body, (zero, tok, pool, pos, live, buf, cnt, made,
-                         zero))
-        return tok, pool, pos, live, made, buf, cnt, steps, occ
+                         zero, jnp.zeros((2,), jnp.int32)))
+        out = (tok, pool, pos, live, made, buf, cnt, steps, occ)
+        return out + (st,) if moe else out
 
     # no donation: the while_loop carries the pool internally (same as
     # the dense chunked loop)
@@ -1079,6 +1092,14 @@ class PagedScheduler(Scheduler):
         # layer, and those it computed (the rest it skips)
         self.attn_cells_computed = 0
         self.attn_cells_grid = 0
+        # fused read of a MoE model only: routed (row, expert) pairs of
+        # the decode steps, and experts with a row summed over layers
+        # and steps (what the grouped kernel reads), from the device
+        self._moe_stats = (self.attn_plan is not None
+                           and bool(model.cfg.num_experts))
+        self._moe_dev = None
+        self.moe_rows_routed = 0
+        self.moe_experts_read = 0
 
     # ------------------------------------------------------ accounting
     def kv_bytes(self) -> int:
@@ -1194,12 +1215,29 @@ class PagedScheduler(Scheduler):
     def _run_chunk(self):
         if self._page_table_dev is None:
             self._page_table_dev = jnp.asarray(self._page_table)
-        (self.tok, self.pool, self.pos, self.live, self.made, buf, cnt,
-         steps, occ) = self._chunk_fn(
+        out = self._chunk_fn(
             self.params, self.tok, self.pool, self._page_table_dev,
             self.pos, self.live, self.made, self.fresh,
             self.max_new_row, self.eos_row)
+        if self._moe_stats:
+            out, self._moe_dev = out[:-1], out[-1]
+        (self.tok, self.pool, self.pos, self.live, self.made, buf, cnt,
+         steps, occ) = out
         return buf, cnt, steps, occ
+
+    def _round_extras(self) -> tuple:
+        """The MoE counts ride the round's one transfer, after the
+        fidelity extras."""
+        extras = super()._round_extras()
+        return extras + (self._moe_dev,) if self._moe_stats else extras
+
+    def _absorb_round_extras(self, extras: tuple) -> None:
+        if self._moe_stats:
+            routed, read = (int(v) for v in extras[-1])
+            self.moe_rows_routed += routed
+            self.moe_experts_read += read
+            extras = extras[:-1]
+        super()._absorb_round_extras(extras)
 
     def _absorb_round(self, out, occupied, elapsed) -> Optional[dict]:
         """Counts the fused read's cells before the base bookkeeping

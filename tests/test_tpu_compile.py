@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import cim_mac, paged_attention as pa
+from repro.kernels import grouped_matmul as gm
 from repro.kernels import ternary_matmul as tm
 
 K, N = 2048, 8192
@@ -114,6 +115,33 @@ def test_paged_attention_keeps_its_name(spec):
              for line in text.splitlines()
              if "tpu_custom_call" in line and " = " in line]
     assert calls and all(c.startswith("%paged_attention.") for c in calls)
+
+
+def test_windowed_paged_attention_compiles(spec):
+    q, kv = _attention_operands(spec)
+    _compile(pa.paged_attention, q, kv._replace(lo=spec((8,), jnp.int32)))
+
+
+# Mellum2's expert matrices (w1/w3 2304 x 896, w2 896 x 2304) over its 64
+# experts: a decode step's 192 routed rows in 70 tiles of 32, a 3072-token
+# prefill's 24,576 in 256 tiles of 128
+GMM_TILES = {"decode": (70, 32), "prefill": (256, 128)}
+
+
+@pytest.mark.parametrize("phase", sorted(GMM_TILES))
+@pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304)])
+@pytest.mark.parametrize("mode", ["base3", "trit2"])
+def test_grouped_matmul_compiles(spec, mode, k, n, phase):
+    tiles, bm = GMM_TILES[phase]
+    kw = k if mode == "base3" else k // tm.TRIT2_PER_BYTE
+    text = _compile(functools.partial(gm.ternary_gmm_int8, mode=mode),
+                    spec((tiles * bm, k), jnp.int8),
+                    spec((tiles * bm,), jnp.float32),
+                    spec((64, kw, n), jnp.uint8), spec((64, n), jnp.float32),
+                    spec((tiles,), jnp.int32), spec((1,), jnp.int32)
+                    ).as_text()
+    # the name the trace shows it under, apart from the dense kernel's
+    assert "%ternary_gmm_int8" in text and "%ternary_matmul_int8" not in text
 
 
 def test_cim_mac_compiles(spec):

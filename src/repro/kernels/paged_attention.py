@@ -92,10 +92,19 @@ class PagedAttentionKV(NamedTuple):
     the plan shape is ``(S*KV*rep, hd, W*page_size)``: queries times
     head dim against the per-slot context capacity.
 
+    The pages may be one layer's pool or every layer's: the decode step
+    passes the whole ``(L, P, ...)`` pool flattened to ``(L*P, ...)``
+    with layer ``l``'s table offset by ``l*P``
+    (``paged_kv.raw_pool_view``), so the pool is read in place and no
+    layer's pages are sliced out of it.
+
     Fields::
 
-      k_pages, v_pages : (num_pages, page_size, KV, hd)  one layer's pool
-      page_table       : (S, W) int32   pool page id per slot x window
+      k_pages, v_pages : (num_pages, page_size, KV, hd)  the pages the
+                                        table indexes (e.g. L*P)
+      page_table       : (S, W) int32   page id per slot x window
+                                        (a row's padding points at a
+                                        null page)
       pos              : (S,) int32     live context length per slot
                                         (0 for a dead slot)
       lo               : (S,) int32     first visible position per slot

@@ -359,10 +359,13 @@ def decode_attention_read(x, w, cfg: ModelConfig, cache: KVCache,
 def paged_decode_attention_read(x, w, cfg: ModelConfig, k_pages, v_pages,
                                 page_table, pos, plan, cim_cfg=None,
                                 layer: Optional[LayerAttn] = None):
-    """Batched one-token decode read straight off one layer's page pool:
-    the planned ``attention`` executor (``kernels.paged_attention``)
+    """Batched one-token decode read straight off the page pool: the
+    planned ``attention`` executor (``kernels.paged_attention``)
     consumes the page table in-kernel, so the gathered dense KV copy the
-    ``slot_view`` path materializes never exists here.  The executor
+    ``slot_view`` path materializes never exists here.  ``k_pages`` /
+    ``v_pages`` are passed through untouched: the fused decode step
+    gives every layer the whole pool flattened to ``(L*P, ...)`` and
+    this layer's ``page_table``, offset to its pages.  The executor
     returns partial flash statistics over the pooled context; the fresh
     token's own k/v merge in with the same two-block rule (and the same
     masking constant) as ``decode_attention_read``, so the two paths

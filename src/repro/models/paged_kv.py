@@ -130,10 +130,17 @@ def slot_view(pool: PagedKVCache, page_table: jax.Array,
     return view
 
 
-def raw_pool_view(pool: PagedKVCache) -> tuple:
-    """The raw ``(L, P, page_size, KV, hd)`` page arrays, for
-    layout-specialized executors that consume the page table in-kernel
+def raw_pool_view(pool: PagedKVCache, page_table: jax.Array) -> tuple:
+    """The raw page arrays with the layer folded into the page axis,
+    ``(L*P, page_size, KV, hd)``, and each layer's page table into
+    them, ``(L, S, W)`` from the ``(S, W)`` table, for layout-specialized
+    executors that consume the page table in-kernel
     (``kernels.paged_attention``) instead of gathering a dense copy.
+    Page ``p`` of layer ``l`` is row ``l*P + p``, so layer ``l`` reads
+    the whole pool through ``page_table + l*P`` (page 0 of each layer,
+    its null page, stays what a padded row reads) and no layer's pages
+    are sliced out.  Merging the two leading axes of the row-major pool
+    is a bitcast.
 
     Float-KV pools only: int8 pools carry per-position scale pages the
     fused read path does not consume — callers (PagedScheduler) fall
@@ -143,7 +150,11 @@ def raw_pool_view(pool: PagedKVCache) -> tuple:
             "raw pool view is float-KV only: int8 page pools carry "
             "scale pages the fused attention read does not consume; "
             "use the slot_view gather path")
-    return pool.k_pages, pool.v_pages
+    n_layers, n_pages = pool.k_pages.shape[:2]
+    k, v = (a.reshape((n_layers * n_pages,) + a.shape[2:])
+            for a in (pool.k_pages, pool.v_pages))
+    offsets = jnp.arange(n_layers, dtype=page_table.dtype) * n_pages
+    return k, v, page_table + offsets[:, None, None]
 
 
 def append_tokens(pool: PagedKVCache, kts: jax.Array, vts: jax.Array,

@@ -454,10 +454,15 @@ class TransformerLM(BaseModel):
     def decode_paged_fused(self, params, tokens, pool, page_table, pos,
                            cim=None, attn_plan=None, live=None):
         """Batched one-token decode straight off the page pool: the
-        layer scan carries each layer's raw page arrays and the planned
-        attention executor (``attn_plan``, an ``op='attention'``
-        ExecutionPlan) reads them through the page table in-kernel —
-        the ``slot_view`` gather copy is never materialized.  Returns
+        planned attention executor (``attn_plan``, an ``op='attention'``
+        ExecutionPlan) reads the whole pool through the page table
+        in-kernel — the ``slot_view`` gather copy is never
+        materialized.  The pool is a loop-invariant of the layer scan,
+        never sliced: every layer reads the whole pool, its layers
+        folded into the page axis, through its own page table
+        (``paged_kv.raw_pool_view``).  A scan over the pool's layer axis
+        would copy every layer's pages out of the pool, the whole pool,
+        on every step.  Returns
         (logits (S, 1, V), kts (L, S, KV, hd), vts, moe_stats (2,)) in
         compute dtype; the scheduler's page scatter is unchanged.
         ``live`` (S,) keeps dead slots out of a dropless MoE's routing;
@@ -473,14 +478,14 @@ class TransformerLM(BaseModel):
                              "one per pool geometry)")
         from . import paged_kv
         cfg = self.cfg
-        k_pages, v_pages = paged_kv.raw_pool_view(pool)
+        k_pages, v_pages, tables = paged_kv.raw_pool_view(pool, page_table)
         x = _take_embed(params["embed"], tokens[:, None]).astype(cfg.dtype)
 
         def body(x, inp):
-            wl, lat, k_l, v_l = inp
+            wl, lat, table = inp
             xa = rms_norm(x, wl["ln1"], cfg.norm_eps)
             out, kt, vt = attn.paged_decode_attention_read(
-                xa, wl, cfg, k_l, v_l, page_table, pos, attn_plan, cim,
+                xa, wl, cfg, k_pages, v_pages, table, pos, attn_plan, cim,
                 layer=lat)
             x = x + out
             m, _, st = self._ffn(rms_norm(x, wl["ln2"], cfg.norm_eps), wl,
@@ -488,8 +493,7 @@ class TransformerLM(BaseModel):
             return x + m, (kt, vt, st)
 
         x, (kts, vts, sts) = jax.lax.scan(
-            body, x, (params["blocks"], self._layer_attn(), k_pages,
-                      v_pages))
+            body, x, (params["blocks"], self._layer_attn(), tables))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         return dense(x, params["unembed"], cim), kts, vts, sts.sum(0)
 

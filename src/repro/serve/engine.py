@@ -1073,6 +1073,7 @@ class PagedScheduler(Scheduler):
         self._admit_fn = make_paged_admit_fn()
         self._write_pages = jax.jit(paged_kv.write_prompt_pages,
                                     donate_argnums=(0,))
+        self._prefills: dict[int, Callable] = {}  # state positions -> step
         self.pool = paged_kv.init_page_pool(model.cfg, self.num_pages,
                                             self.page_size)
         self.allocator = paged_kv.PageAllocator(self.num_pages,
@@ -1124,6 +1125,18 @@ class PagedScheduler(Scheduler):
         return self.allocator.prefix_hit_rate
 
     # -------------------------------------------------------- admission
+    def _prefill_for(self, positions: int) -> Callable:
+        # the dense prefill state lives only until its pages are written
+        # into the pool, so it holds the prompt's pages, not the whole
+        # capacity: admissions queued behind a running chunk each keep
+        # one such state on the device until the write runs.  Prefill
+        # attends over the prompt alone, so tokens do not depend on it;
+        # a prompt length compiles one prefill either way.
+        if positions not in self._prefills:
+            self._prefills[positions] = make_prefill_step(
+                self.model, positions, self.cim_prefill)
+        return self._prefills[positions]
+
     def _admit(self, req: Request, slot: int) -> bool:
         ps = self.page_size
         s_len = len(req.prompt)
@@ -1152,10 +1165,10 @@ class PagedScheduler(Scheduler):
         # device: batch-1 prefill, then scatter its KV into the fresh
         # pages (shared hits already hold the identical bits)
         with _span("serve.prefill", uid=req.uid, prompt_len=s_len):
-            tok0, st = self._prefill(self.params,
-                                     _batch_inputs([req], self.extra_inputs))
-            self.steps_run += 1
             n_prompt = -(-s_len // ps)
+            tok0, st = self._prefill_for(n_prompt * ps)(
+                self.params, _batch_inputs([req], self.extra_inputs))
+            self.steps_run += 1
             hit = set(shared)
             write_src = [j for j in range(n_prompt) if j not in hit]
             if write_src:

@@ -93,6 +93,21 @@ def test_paged_tokens_match_all_drivers():
     assert all(len(outs[0][uid]) == mn for uid, _, mn in specs)
 
 
+def test_admission_prefill_state_holds_the_prompt_pages():
+    """An admission's dense prefill state lives only until its pages are
+    written into the pool, so it holds the prompt's pages, not the
+    request capacity: one prefill per page count, each state that many
+    positions (tokens are pinned by the parity tests above)."""
+    cfg, model, params = _setup()
+    eng = PagedScheduler(model, params, capacity=32, slots=4, chunk=3,
+                         page_size=4)
+    _run(eng, _requests(cfg, [(0, 8, 5), (1, 6, 3), (2, 9, 2)]))
+    assert sorted(eng._prefills) == [8, 12]
+    _, st = jax.eval_shape(eng._prefills[12], params,
+                           {"tokens": jnp.zeros((1, 9), jnp.int32)})
+    assert st["k"].shape[2] == 12
+
+
 def test_paged_page_size_invariance():
     """The page size is a storage choice, not a semantics choice: every
     page size yields the same tokens."""
@@ -601,6 +616,64 @@ def test_paged_attention_kernel_matches_gather_oracle(lens, nan_past_live):
         assert (np.asarray(l[i]) == 0).all()
         assert (np.asarray(acc[i]) == 0).all()
         assert (np.asarray(m[i]) == pa.NEG_INF).all()
+
+
+@pytest.mark.parametrize("lens,lo", [
+    ([29, 17, 32], None),       # every slot live, page-unaligned too
+    ([0, 16, 13], None),        # dead, page-aligned, mid-page
+    ([29, 17, 32], [12, 0, 20]),     # window layer: lo skips from below
+], ids=["all_live", "dead_slot", "window"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_attention_reads_its_layer_of_the_whole_pool(layer, lens,
+                                                          lo):
+    """The fused decode step hands every layer the whole pool, flattened
+    to ``(L*P, ...)``, and the layer's page table offset by
+    ``layer * P``, both from ``paged_kv.raw_pool_view``.  Through that
+    view the kernel must return the statistics of the layer's own
+    pages: the same as the oracle over the layer's slab alone, and
+    unlike any other layer's (each layer of the pool holds different
+    values), so a wrong or missing offset cannot pass."""
+    from repro.kernels import paged_attention as pa
+    n_layers, s, kvh, rep, hd, ps, w = 3, 3, 2, 3, 16, 8, 4
+    n_pages = 1 + s * w
+    key = jax.random.key(5)
+    q = jax.random.normal(jax.random.fold_in(key, 0), (s, kvh, rep, hd),
+                          jnp.float32)
+    shape = (n_layers, n_pages, ps, kvh, hd)
+    pool = paged_kv.PagedKVCache(
+        jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.float32),
+        jax.random.normal(jax.random.fold_in(key, 2), shape, jnp.float32))
+    table = jnp.arange(1, n_pages, dtype=jnp.int32).reshape(s, w)
+    k_flat, v_flat, tables = paged_kv.raw_pool_view(pool, table)
+    assert k_flat.shape == (n_layers * n_pages, ps, kvh, hd)
+    np.testing.assert_array_equal(np.asarray(tables[layer]),
+                                  np.asarray(table) + layer * n_pages)
+    pos = jnp.asarray(lens, jnp.int32)
+    lo = None if lo is None else jnp.asarray(lo, jnp.int32)
+    kv = pa.PagedAttentionKV(k_flat, v_flat, tables[layer], pos, lo)
+
+    def own(l):
+        return pa.paged_attention_ref(q, pa.PagedAttentionKV(
+            pool.k_pages[l], pool.v_pages[l], table, pos, lo))
+
+    acc, m, l = pa.paged_attention(q, kv, interpret=True)
+    # the oracle reads the same operand, to the bit
+    for a, b in zip(pa.paged_attention_ref(q, kv), own(layer)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    acc_r, m_r, l_r = own(layer)
+    np.testing.assert_allclose(np.asarray(m), np.asarray(m_r),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(l), np.asarray(l_r), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(acc), np.asarray(acc_r),
+                               rtol=1e-5, atol=1e-5)
+    live = np.asarray(pos) > 0
+    for other in set(range(n_layers)) - {layer}:
+        acc_o = np.asarray(own(other)[0])
+        assert not np.allclose(np.asarray(acc)[live], acc_o[live],
+                               rtol=1e-2, atol=1e-2)
+    for i in np.flatnonzero(~live):
+        assert (np.asarray(l[i]) == 0).all()
+        assert (np.asarray(acc[i]) == 0).all()
 
 
 # ------------------------------------------------- bench contract

@@ -5,7 +5,8 @@ topology: the TPU compiler is installed here and refuses what the chip
 would refuse (tile layouts, integer ops Mosaic cannot lower, VMEM).
 Nothing runs.  Widths are internlm2-1.8b's: d_model 2048 (K), d_ff 8192
 (N), decode M=8 and prefill M=512; the attention read uses the serving
-pool geometry of ``chip_smoke.py``.
+pool geometry of ``chip_smoke.py``.  One test compiles the whole fused
+paged decode chunk, to see how the pool reaches the kernel.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports
@@ -17,6 +18,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels import cim_mac, paged_attention as pa
@@ -120,6 +122,58 @@ def test_paged_attention_keeps_its_name(spec):
 def test_windowed_paged_attention_compiles(spec):
     q, kv = _attention_operands(spec)
     _compile(pa.paged_attention, q, kv._replace(lo=spec((8,), jnp.int32)))
+
+
+def test_fused_decode_reads_the_pool_in_place(spec):
+    """The fused paged decode chunk, at internlm2-1.8b widths with 2
+    layers over the chat cell's page geometry (512 pages of 128 rows),
+    reads the KV pool where it lies: no instruction outputs one layer's
+    ``(P, ps, KV, hd)`` slab of the pool, and the chunk's scratch stays
+    under one such slab.  A slab small enough for on-chip memory would
+    be placed there and not show in the scratch, so the pool is the
+    cell's own size (it is only described, never allocated).  The only
+    pool-sized copies left are the chunk's copies of its undonated K
+    and V pool parameters."""
+    import dataclasses
+    import re
+    from repro import configs
+    from repro.kernels import plan_matmul
+    from repro.models import paged_kv, registry
+    from repro.serve.engine import make_paged_decode_loop
+    cfg = dataclasses.replace(configs.get("internlm2-1.8b"), num_layers=2,
+                              dtype=jnp.bfloat16)
+    model = registry.build(cfg)
+    s, w, ps, n_pages = 8, 4, 128, 512
+    plan = plan_matmul((s * cfg.num_heads, cfg.hd, w * ps), "decode",
+                       op="attention", domain="float", kv_layout="paged",
+                       interpret=False)
+    fn = make_paged_decode_loop(model, 8, attn_plan=plan)
+
+    def described(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+    params = described(jax.eval_shape(model.init, jax.random.key(0)))
+    pool = described(jax.eval_shape(
+        lambda: paged_kv.init_page_pool(cfg, n_pages, ps)))
+    i32, pred = (lambda *d: spec(d, jnp.int32)), (lambda *d: spec(d, bool))
+    compiled = _compile(fn, params, i32(s), pool, i32(s, w), i32(s),
+                        pred(s), i32(s), pred(s), i32(s), i32(s))
+    slab_shape = (n_pages, ps, cfg.num_kv_heads, cfg.hd)
+    slab_bytes = 2 * int(np.prod(slab_shape))
+    assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes
+    slab = "bf16[%s]" % ",".join(map(str, slab_shape))
+    whole = "bf16[%d,%s]" % (cfg.num_layers,
+                             ",".join(map(str, slab_shape)))
+    pool_copies = 0
+    for line in compiled.as_text().splitlines():
+        if " = " not in line:
+            continue
+        rhs = line.split(" = ", 1)[1]
+        # the output type: everything before the opcode and its operands
+        out = re.split(r"\s[a-z][\w.-]*\(", rhs, maxsplit=1)[0]
+        assert slab not in out, line
+        if out.startswith(whole) and " copy(" in rhs:
+            pool_copies += 1
+    assert pool_copies <= 2
 
 
 # Mellum2's expert matrices (w1/w3 2304 x 896, w2 896 x 2304) over its 64

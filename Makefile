@@ -5,7 +5,7 @@
 # reproducible regardless of the caller's environment.
 XLA_DEVICES ?= 8
 
-.PHONY: verify test test-fast ci analyze dryrun-smoke bench bench-compare
+.PHONY: verify test test-fast ci analyze dryrun-smoke bench
 
 verify: test
 
@@ -20,9 +20,9 @@ test-fast:
 	XLA_DEVICES=$(XLA_DEVICES) scripts/verify.sh -m "not slow"
 
 # the full CI pipeline locally: analysis gate + tier-1 suite + the
-# bench schema gate + the perf-regression gate — exactly what
-# .github/workflows/ci.yml runs (as separate jobs)
-ci: analyze test bench bench-compare
+# bench schema gate — exactly what .github/workflows/ci.yml runs (as
+# separate jobs)
+ci: analyze test bench
 
 # static contract checker + sanitizer (src/repro/analysis/README.md):
 # capability lattice vs the kernels README matrix, pallas block/index
@@ -36,23 +36,15 @@ analyze:
 	    PYTHONPATH=src python -m repro.analysis \
 	    --out experiments/analysis/findings.json
 
-# perf-trajectory benchmarks (kernel_bench + wallclock, reduced sweeps)
-# under the same 8-fake-device env as the tests; fails if the tracked
-# BENCH_wallclock.json baseline or the regenerated (gitignored)
-# experiments/benchmarks/*.json copies are missing or schema-invalid
-# (benchmarks/schema.py). Only `python -m benchmarks.wallclock`
-# rewrites the tracked baseline.
+# kernel_bench (oracle match, VMEM working sets, HBM density) under
+# the same 8-fake-device env as the tests; fails if the tracked
+# BENCH_autotune.json or the regenerated (gitignored)
+# experiments/benchmarks/kernel_bench.json is missing or
+# schema-invalid (benchmarks/schema.py). Speed is measured on the chip
+# by bench/run.py (BENCHMARK.json), not here.
 bench:
 	XLA_FLAGS="--xla_force_host_platform_device_count=$(XLA_DEVICES)" \
 	    PYTHONPATH=src python -m benchmarks.run --fast
-
-# perf-regression gate: diff the regenerated (gitignored)
-# experiments/benchmarks/wallclock.json against the tracked
-# BENCH_wallclock.json baseline — every tok_per_s_* / step_time_s*
-# metric, semantic shape-cell keys, fail on >15% regression
-# (benchmarks/compare.py). Run `make bench` first.
-bench-compare:
-	PYTHONPATH=src python -m benchmarks.compare
 
 # one dry-run cell as a launcher smoke check (compiles a 256-chip train
 # step against ShapeDtypeStructs; no allocation)

@@ -29,17 +29,6 @@ def save_json(name: str, payload: dict) -> str:
     return path
 
 
-def save_bench_json(name: str, payload: dict) -> str:
-    """Write a tracked perf-trajectory artifact (BENCH_<name>.json at the
-    repo root — the wall-clock numbers later perf PRs are judged against),
-    in addition to the experiments/ copy."""
-    save_json(name, payload)
-    path = os.path.join(REPO_ROOT, f"BENCH_{name}.json")
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=1, default=str)
-    return path
-
-
 def stable_seed(*parts) -> int:
     """PYTHONHASHSEED-independent seed from a tuple of ints/strings
     (builtin hash() of str is salted per process — irreproducible).
@@ -47,30 +36,6 @@ def stable_seed(*parts) -> int:
     the benchmarks-facing alias the RA004 lint rule recognizes."""
     from repro.core.seeding import stable_seed as _stable_seed
     return _stable_seed(*parts)
-
-
-def time_fn(fn, *args, warmup: int = 1, iters: int = 5,
-            min_total: float = 0.25, max_iters: int = 40, **kw) -> float:
-    """Best-of-N wall-clock seconds of fn(*args) with jit warmup and
-    block_until_ready on the result.  The minimum, not the median:
-    scheduler noise on shared hosts only ever ADDS time, and the
-    bench-compare regression gate needs a statistic stable enough
-    that a 15% threshold measures the code, not the host (the serve
-    benches and the autotuner already time best-of-N).  At least
-    `iters` samples, then more until `min_total` seconds of
-    measurement (capped at `max_iters`) — a fixed, pre-registered
-    budget rule, so sub-millisecond steps get the many samples their
-    process-to-process jitter needs while multi-ms steps stop early."""
-    import jax
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args, **kw))
-    best, total, n = float("inf"), 0.0, 0
-    while n < iters or (total < min_total and n < max_iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args, **kw))
-        dt = time.perf_counter() - t0
-        best, total, n = min(best, dt), total + dt, n + 1
-    return best
 
 
 # ------------------------------------------------------------------ MLP

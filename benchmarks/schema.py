@@ -1,169 +1,26 @@
-"""Schema contracts for the tracked benchmark JSON artifacts.
+"""Schema contracts for the benchmark JSON artifacts.
 
 `make bench` (and tests) validate the artifacts against these minimal
-required-key sets so a refactor cannot silently drop a field the perf
-trajectory depends on.  Keys here are a floor, not a ceiling — suites
-may add fields freely.
+required-key sets so a refactor cannot silently drop a field a reader
+depends on.  Keys here are a floor, not a ceiling — suites may add
+fields freely.
 """
 from __future__ import annotations
 
 import json
 import os
 
-# device-fidelity accuracy bound: the smoke classifier served through
-# the fault-injected analog path at the MEASURED TL restore yield may
-# lose at most this much accuracy vs the exact ternary kernels (the
-# acceptance bound serve_fidelity's claim is judged against — pinned
-# HERE so a bench edit cannot quietly relax it)
-FIDELITY_ACC_DROP_MAX = 0.05
-
 # artifact name -> required top-level keys
 TOP_LEVEL = {
-    "wallclock": {
-        "backend", "platform", "shapes", "serve", "serve_continuous",
-        "serve_paged", "serve_fidelity", "serve_frontend",
-        "min_decode_flop_waste_reduction",
-        "claim_waste_reduction_ge_8x",
-        "claim_device_loop_single_transfer",
-        "claim_loops_token_identical",
-        "claim_continuous_beats_bucket_tokps",
-        "claim_continuous_beats_bucket_p99",
-        "claim_continuous_tokens_identical",
-        "claim_chunk_transfer_accounting",
-        "claim_paged_tokens_identical",
-        "claim_paged_kv_bytes_2x",
-        "claim_paged_prefix_hits",
-        "claim_paged_fused_tokens_identical",
-        "claim_paged_fused_beats_gather",
-        "claim_paged_fused_hbm_lt_gather",
-        "claim_fidelity_accuracy_within_bound",
-        "claim_fidelity_degrades_without_scrub",
-        "claim_fidelity_scrub_repairs",
-        "claim_fidelity_transfer_accounting",
-        "claim_frontend_tokens_identical",
-        "claim_frontend_backpressure_bounded",
-        "claim_frontend_goodput_under_overload",
-        "claim_frontend_transfer_accounting",
-    },
     "kernel_bench": {
         "sweep", "max_rel_err", "all_match_oracle",
         "vmem_working_set_bytes", "hbm_density",
     },
 }
 
-# wallclock per-shape-cell required keys
-WALLCLOCK_CELL = {
-    "phase", "m", "k", "n", "mode", "plan", "plan_int8",
-    "blocks_adaptive", "blocks_fixed",
-    "flops_ideal", "flops_padded_adaptive", "flops_padded_fixed",
-    "flop_waste_adaptive", "flop_waste_fixed", "flop_waste_reduction",
-    "hbm_bytes_adaptive", "hbm_bytes_fixed",
-}
-
-# each cell's resolved-plan record (kernels.ExecutionPlan.describe):
-# which backend/domain/blocks — and, since the device backend landed,
-# which FIDELITY — actually produced the step timings
-WALLCLOCK_PLAN = {"backend", "domain", "packing", "blocks", "fidelity"}
-
-# wallclock serve_continuous section: the continuous-vs-bucket artifact
-# contract (ROADMAP §Performance)
-SERVE_CONTINUOUS = {
-    "slots", "chunk", "trace", "bucket", "continuous",
-    "claim_continuous_beats_bucket_tokps",
-    "claim_continuous_beats_bucket_p99",
-    "claim_continuous_tokens_identical",
-    "claim_chunk_transfer_accounting",
-}
-SERVE_CONTINUOUS_DRIVER = {"tok_per_s", "wall_s", "tokens", "p50_s",
-                           "p99_s", "p999_s", "queue_wait_mean_s",
-                           "service_mean_s"}
-SERVE_CONTINUOUS_ONLY = {"slot_occupancy", "host_transfers", "chunks",
-                         "decode_steps"}
-
-# wallclock serve_paged section: the paged-vs-dense slot-pool artifact
-# contract (resident KV bytes, page accounting, prefix sharing, tok/s
-# at equal pool width/memory budget) + the fused-vs-gather decode read
-# (the planned paged_attn executor vs the slot_view gather path: both
-# tok/s, measured chunk byte traffic, and the basis the beats-gather
-# claim was judged on — wallclock where the kernel lowers natively,
-# byte traffic under interpret emulation)
-SERVE_PAGED = {
-    "slots", "chunk", "capacity", "page_size", "num_pages", "trace",
-    "tok_per_s_dense", "tok_per_s_paged",
-    "kv_bytes_dense", "kv_bytes_paged_pool", "kv_bytes_paged_peak",
-    "kv_bytes_reduction", "pages_in_use_peak", "prefix_hit_rate",
-    "attn_plan", "tok_per_s_paged_fused", "tok_per_s_paged_gather",
-    "hbm_bytes_chunk_fused", "hbm_bytes_chunk_gather",
-    "hbm_bytes_reduction", "hbm_bytes_source", "fused_claim_basis",
-    "latency_dense", "latency_paged", "ungated_metrics",
-    "claim_paged_tokens_identical",
-    "claim_paged_kv_bytes_2x",
-    "claim_paged_prefix_hits",
-    "claim_paged_fused_tokens_identical",
-    "claim_paged_fused_beats_gather",
-    "claim_paged_fused_hbm_lt_gather",
-}
-
-# the two bases a committed artifact may judge the fused beats-gather
-# claim on (the full prose after the token explains the choice)
-FUSED_CLAIM_BASES = {"wallclock", "hbm-bytes"}
-
-# wallclock serve_fidelity section: device-fidelity serving at the
-# measured TL restore yield — accuracy vs the schema-pinned bound,
-# scrub-gate error rates (repair must be measured, not a no-op),
-# throughput, ADC clip counters, and the scrub restore-energy cost
-SERVE_FIDELITY = {
-    "fault_model", "plan_exact", "plan_device",
-    "acc_float", "acc_exact", "acc_device", "acc_drop", "acc_drop_max",
-    "tok_per_s_exact", "tok_per_s_device", "token_agreement",
-    "err_with_scrub", "err_no_scrub", "scrub_residual_bound",
-    "scrubs_run", "adc_clip_lo", "adc_clip_hi",
-    "host_transfers_device", "chunks_device",
-    "scrub_energy_j", "scrub_energy_j_per_token",
-    "claim_fidelity_accuracy_within_bound",
-    "claim_fidelity_degrades_without_scrub",
-    "claim_fidelity_scrub_repairs",
-    "claim_fidelity_transfer_accounting",
-}
-
-# wallclock serve_frontend section: the SLO-aware front-end over the
-# model registry (repro.frontend) — parity + throughput vs driving the
-# schedulers directly, the bounded-backpressure overload replay, and
-# the goodput (deadline-met tok/s) comparison of SLO admission vs the
-# FIFO baseline.  FIFO-under-overload is the adversarial baseline, so
-# its goodput lives in ungated_metrics (the schema checks it is there)
-SERVE_FRONTEND = {
-    "models", "queue_limit", "overload_queue_limit",
-    "tok_per_s_frontend", "tok_per_s_direct",
-    "frontend", "overload",
-    "deadline_tight_s", "service_floor_s",
-    "tok_per_s_goodput_slo", "tok_per_s_goodput_fifo",
-    "deadline_met_slo", "deadline_met_fifo", "deadline_total",
-    "shed_slo", "ungated_metrics",
-    "claim_frontend_tokens_identical",
-    "claim_frontend_backpressure_bounded",
-    "claim_frontend_goodput_under_overload",
-    "claim_frontend_transfer_accounting",
-}
-# one warm open-loop epoch's stats (the `frontend` sub-dict): latency
-# percentiles with the queue-wait/service split, TTFT, and the
-# streaming transfer accounting
-SERVE_FRONTEND_EPOCH = {
-    "wall_s", "tokens", "p50_s", "p99_s", "p999_s",
-    "ttft_p50_s", "ttft_p99_s", "queue_wait_mean_s", "service_mean_s",
-    "host_transfers", "chunks",
-}
-# the backpressure replay (the `overload` sub-dict): every submit must
-# be accounted for — completed + rejected, nothing silently dropped
-SERVE_FRONTEND_OVERLOAD = {
-    "submitted", "completed", "rejected", "max_pending_seen",
-    "rejects_by_reason",
-}
-
 
 def validate(name: str, payload: dict) -> list[str]:
     """Return a list of schema violations (empty = valid)."""
-    errors = []
     if name == "autotune":
         # the measured block-shape table: one contract, shared with the
         # runtime loader and the `make analyze` autotune pass
@@ -172,185 +29,13 @@ def validate(name: str, payload: dict) -> list[str]:
                 for rule, where, message in validate_table(payload)]
     required = TOP_LEVEL.get(name)
     if required is None:
-        return errors                       # no contract for this artifact
+        return []                           # no contract for this artifact
     if not isinstance(payload, dict):
         return [f"{name}: top level is {type(payload).__name__}, not object"]
     missing = required - payload.keys()
     if missing:
-        errors.append(f"{name}: missing top-level keys {sorted(missing)}")
-    if name == "wallclock":
-        for i, cell in enumerate(payload.get("shapes", [])):
-            miss = WALLCLOCK_CELL - cell.keys()
-            if miss:
-                errors.append(f"wallclock shapes[{i}]: missing "
-                              f"{sorted(miss)}")
-            for pk in ("plan", "plan_int8"):
-                if pk not in cell:
-                    continue               # absence reported above
-                rec = cell[pk]
-                if not isinstance(rec, dict):
-                    errors.append(f"wallclock shapes[{i}].{pk}: expected "
-                                  f"object, got {type(rec).__name__}")
-                    continue
-                pmiss = WALLCLOCK_PLAN - rec.keys()
-                if pmiss:
-                    errors.append(f"wallclock shapes[{i}].{pk}: missing "
-                                  f"{sorted(pmiss)}")
-        if not payload.get("shapes"):
-            errors.append("wallclock: empty shapes sweep")
-        sc = payload.get("serve_continuous")
-        if isinstance(sc, dict):
-            miss = SERVE_CONTINUOUS - sc.keys()
-            if miss:
-                errors.append(f"wallclock serve_continuous: missing "
-                              f"{sorted(miss)}")
-            for drv in ("bucket", "continuous"):
-                sub = sc.get(drv)
-                if not isinstance(sub, dict):
-                    if drv in sc:          # present but malformed
-                        errors.append(f"wallclock serve_continuous."
-                                      f"{drv}: not an object")
-                    continue               # absent: already reported
-                need = SERVE_CONTINUOUS_DRIVER | (
-                    SERVE_CONTINUOUS_ONLY if drv == "continuous"
-                    else set())
-                miss = need - sub.keys()
-                if miss:
-                    errors.append(f"wallclock serve_continuous.{drv}: "
-                                  f"missing {sorted(miss)}")
-        elif "serve_continuous" in payload:
-            errors.append("wallclock serve_continuous: not an object")
-        sp = payload.get("serve_paged")
-        if isinstance(sp, dict):
-            miss = SERVE_PAGED - sp.keys()
-            if miss:
-                errors.append(f"wallclock serve_paged: missing "
-                              f"{sorted(miss)}")
-            rec = sp.get("attn_plan")
-            if isinstance(rec, dict):
-                pmiss = WALLCLOCK_PLAN - rec.keys()
-                if pmiss:
-                    errors.append(f"wallclock serve_paged.attn_plan: "
-                                  f"missing {sorted(pmiss)}")
-                # the fused measurement must have run the fused
-                # executor, not a fallback
-                if rec.get("backend") != "paged_attn":
-                    errors.append(
-                        f"wallclock serve_paged.attn_plan: backend "
-                        f"{rec.get('backend')!r} is not 'paged_attn'")
-            elif "attn_plan" in sp:
-                errors.append("wallclock serve_paged.attn_plan: not an "
-                              "object")
-            basis = sp.get("fused_claim_basis")
-            if isinstance(basis, str) and \
-                    basis.split()[0] not in FUSED_CLAIM_BASES:
-                errors.append(
-                    f"wallclock serve_paged: fused_claim_basis "
-                    f"{basis!r} does not start with one of "
-                    f"{sorted(FUSED_CLAIM_BASES)}")
-            ungated = sp.get("ungated_metrics")
-            if isinstance(ungated, list):
-                for key in ungated:
-                    if key not in sp:
-                        errors.append(
-                            f"wallclock serve_paged: ungated_metrics "
-                            f"names absent key {key!r}")
-                # an interpret-emulation wallclock number must never be
-                # gated as a perf claim by benchmarks/compare.py
-                if isinstance(basis, str) \
-                        and not basis.startswith("wallclock") \
-                        and "tok_per_s_paged_fused" not in ungated:
-                    errors.append(
-                        "wallclock serve_paged: fused_claim_basis is "
-                        "not wallclock but tok_per_s_paged_fused is "
-                        "missing from ungated_metrics")
-            elif "ungated_metrics" in sp:
-                errors.append("wallclock serve_paged: ungated_metrics "
-                              "is not a list")
-        elif "serve_paged" in payload:
-            errors.append("wallclock serve_paged: not an object")
-        sf = payload.get("serve_fidelity")
-        if isinstance(sf, dict):
-            miss = SERVE_FIDELITY - sf.keys()
-            if miss:
-                errors.append(f"wallclock serve_fidelity: missing "
-                              f"{sorted(miss)}")
-            for pk in ("plan_exact", "plan_device"):
-                rec = sf.get(pk)
-                if not isinstance(rec, dict):
-                    continue               # absence reported above
-                pmiss = WALLCLOCK_PLAN - rec.keys()
-                if pmiss:
-                    errors.append(f"wallclock serve_fidelity.{pk}: "
-                                  f"missing {sorted(pmiss)}")
-            if isinstance(sf.get("plan_device"), dict) and \
-                    sf["plan_device"].get("fidelity") != "device":
-                errors.append("wallclock serve_fidelity.plan_device: "
-                              "fidelity is not 'device'")
-            # the bound is pinned here, not in the bench: an artifact
-            # claiming the accuracy gate against a looser bound fails
-            if "acc_drop_max" in sf and \
-                    sf["acc_drop_max"] != FIDELITY_ACC_DROP_MAX:
-                errors.append(
-                    f"wallclock serve_fidelity: acc_drop_max "
-                    f"{sf['acc_drop_max']} != schema-pinned "
-                    f"{FIDELITY_ACC_DROP_MAX}")
-        elif "serve_fidelity" in payload:
-            errors.append("wallclock serve_fidelity: not an object")
-        sfr = payload.get("serve_frontend")
-        if isinstance(sfr, dict):
-            miss = SERVE_FRONTEND - sfr.keys()
-            if miss:
-                errors.append(f"wallclock serve_frontend: missing "
-                              f"{sorted(miss)}")
-            fe = sfr.get("frontend")
-            if isinstance(fe, dict):
-                fmiss = SERVE_FRONTEND_EPOCH - fe.keys()
-                if fmiss:
-                    errors.append(f"wallclock serve_frontend.frontend: "
-                                  f"missing {sorted(fmiss)}")
-            elif "frontend" in sfr:
-                errors.append("wallclock serve_frontend.frontend: not "
-                              "an object")
-            ov = sfr.get("overload")
-            if isinstance(ov, dict):
-                omiss = SERVE_FRONTEND_OVERLOAD - ov.keys()
-                if omiss:
-                    errors.append(f"wallclock serve_frontend.overload: "
-                                  f"missing {sorted(omiss)}")
-                # the no-silent-drop contract, structurally: every
-                # submit of the overload replay is accounted for
-                elif ov["submitted"] != ov["completed"] + ov["rejected"]:
-                    errors.append(
-                        f"wallclock serve_frontend.overload: "
-                        f"{ov['submitted']} submitted != "
-                        f"{ov['completed']} completed + "
-                        f"{ov['rejected']} rejected (a request was "
-                        f"silently dropped)")
-            elif "overload" in sfr:
-                errors.append("wallclock serve_frontend.overload: not "
-                              "an object")
-            ungated = sfr.get("ungated_metrics")
-            if isinstance(ungated, list):
-                for key in ungated:
-                    if key not in sfr:
-                        errors.append(
-                            f"wallclock serve_frontend: ungated_metrics "
-                            f"names absent key {key!r}")
-                # the FIFO-baseline goodput is adversarial by design;
-                # it must never be gated as a perf claim
-                if "tok_per_s_goodput_fifo" not in ungated:
-                    errors.append(
-                        "wallclock serve_frontend: "
-                        "tok_per_s_goodput_fifo is missing from "
-                        "ungated_metrics (the adversarial FIFO "
-                        "baseline must not be regression-gated)")
-            elif "ungated_metrics" in sfr:
-                errors.append("wallclock serve_frontend: "
-                              "ungated_metrics is not a list")
-        elif "serve_frontend" in payload:
-            errors.append("wallclock serve_frontend: not an object")
-    return errors
+        return [f"{name}: missing top-level keys {sorted(missing)}"]
+    return []
 
 
 def validate_file(path: str) -> list[str]:

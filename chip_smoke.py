@@ -51,8 +51,8 @@ KERNEL_K, KERNEL_N, KERNEL_MS = 2048, 8192, (8, 512)
 # serving pool geometry: slots, pages per slot, page size, KV heads,
 # query heads per KV head, head dim
 ATTN_GEOMETRY = (8, 16, 16, 8, 2, 128)
-SERVE_ARGV = ["--arch", ARCH, "--domain", "int8", "--continuous",
-              "--kv", "paged", "--requests", "8", "--prompt-len", "128",
+SERVE_ARGV = ["--arch", ARCH, "--domain", "int8", "--kv", "paged",
+              "--requests", "8", "--prompt-len", "128",
               "--max-new", "32", "--slots", "8", "--chunk", "8",
               "--capacity", "256", "--page-size", "16"]
 F32_U = 2.0 ** -24      # unit round-off of f32

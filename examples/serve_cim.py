@@ -6,18 +6,19 @@ storage density + ternary MACs; this is its system-level image).
 
 Flow: init model -> quantize every matmul weight to the paper's 5-trit
 base3 format (2x denser than bf16; trit2 is 8x) -> submit a batch of
-requests -> continuous greedy decoding -> report density + throughput.
+requests -> continuous-batching greedy decoding -> report density +
+throughput.
 """
 import argparse
 import time
 
 import jax
-import jax.numpy as jnp
 
 from repro import configs
 from repro.core.cim_linear import CIMConfig, hbm_bytes, ternarize_params
+from repro.launch.serve import extra_inputs
 from repro.models import registry
-from repro.serve import Request, ServeEngine
+from repro.serve import PagedScheduler, Request, Scheduler
 
 
 def main():
@@ -40,15 +41,10 @@ def main():
           f"(matmul weights at "
           f"{'1 byte / 5-trit weight' if args.packing == 'base3' else '2 bits/trit'})")
 
-    extra = {}
-    if cfg.family == "audio":
-        extra["frames"] = lambda b: jnp.zeros((b, cfg.encoder_seq,
-                                               cfg.d_model), cfg.dtype)
-    if cfg.family == "vlm":
-        extra["patches"] = lambda b: jnp.zeros((b, cfg.encoder_seq,
-                                                cfg.d_model), cfg.dtype)
-    eng = ServeEngine(model, packed, capacity=128, max_batch=4, cim=cim,
-                      extra_inputs=extra)
+    # the paged, prefix-shared KV pool where the model's decode pages
+    pool = PagedScheduler if model.supports_paged_kv else Scheduler
+    eng = pool(model, packed, capacity=128, slots=4, cim=cim,
+               extra_inputs=extra_inputs(cfg))
     key = jax.random.key(7)
     for i in range(args.requests):
         prompt = jax.random.randint(jax.random.fold_in(key, i), (24,), 0,

@@ -6,7 +6,7 @@ the loop: ``tune()`` benchmarks a small candidate set of aligned
 ``(bm, bn, bk)`` tiles per ``(shape, phase, platform, packing, domain)``
 cell — once, on the platform that will serve them — and persists the
 winners as a schema-validated JSON artifact (``BENCH_autotune.json`` at
-the repo root, tracked like the wallclock baseline).
+the repo root, tracked).
 
 Plan resolution (``plan.\\_resolve``) consults the table through
 :func:`lookup_blocks`: a warm hit resolves the measured blocks into the
@@ -39,8 +39,9 @@ _REPO_ROOT = os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", "..", ".."))
 DEFAULT_TABLE_BASENAME = "BENCH_autotune.json"
 
-# tuning sweep: mirrors benchmarks/wallclock.py DECODE/PREFILL_SHAPES
-# (the shapes the tracked perf trajectory is measured on)
+# tuning sweep (M, K, N): decode token batches through a 1024x1024
+# projection, and prefill rows (batch x seq) through 1024- and
+# 512-deep ones
 DECODE_SHAPES = ((1, 1024, 1024), (4, 1024, 1024), (8, 1024, 1024),
                  (16, 1024, 1024))
 PREFILL_SHAPES = ((128, 1024, 1024), (256, 512, 1024))
@@ -320,7 +321,7 @@ def measure_cell(m: int, k: int, n: int, phase: str, packing: str,
                  domain: str, iters: int = 3,
                  candidate_limit: int = 8) -> dict:
     """Race the candidate tiles through the real pallas execute path
-    (jitted, same operand recipe as benchmarks/wallclock.py) and return
+    (jitted, on seeded normal operands) and return
     the winning entry for this cell on the current platform."""
     import functools
 
@@ -359,7 +360,7 @@ def measure_cell(m: int, k: int, n: int, phase: str, packing: str,
 def tune(fast: bool = False, iters: int = 3, verbose: bool = False,
          merge_with: Optional[list] = None) -> list:
     """Measure every ``(shape, phase, packing, domain)`` cell of the
-    wallclock sweep on the current platform; returns the merged entry
+    tuning sweep on the current platform; returns the merged entry
     list (existing entries for OTHER platforms/cells are kept, this
     platform's sweep cells are replaced by fresh measurements)."""
     from .plan import DOMAINS, PACKINGS
@@ -398,7 +399,7 @@ def main(argv: Optional[list] = None) -> int:
 
     p = argparse.ArgumentParser(
         prog="python -m repro.kernels.autotune",
-        description="Measure (bm, bn, bk) tiles per wallclock-sweep "
+        description="Measure (bm, bn, bk) tiles per tuning-sweep "
                     "cell and persist the table plan resolution "
                     "consults.")
     p.add_argument("--out", default=None,

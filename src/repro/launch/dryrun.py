@@ -56,7 +56,6 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False,
     from repro.dist import variants
     from repro.launch.input_specs import (abstract_cache,
                                           abstract_model_params,
-                                          decode_loop_specs,
                                           decode_token_spec,
                                           paged_pool_specs,
                                           prefill_batch_specs,
@@ -173,6 +172,9 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False,
             raise ValueError("--kv paged requires --continuous SLOTS "
                              "(the paged pool is a continuous-batching "
                              "slot-pool layout)")
+        if decode_loop and not continuous:
+            raise ValueError("--decode-loop is the chunk budget of a "
+                             "--continuous SLOTS round")
         if continuous and kv_layout == "paged":
             # paged-KV slot pool: lower one chunked round of the paged
             # scheduler loop (serve.make_paged_decode_loop) — the page
@@ -219,27 +221,6 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False,
             tokens = continuous * chunk
             meta["continuous_slots"] = continuous
             meta["chunk"] = chunk
-        elif decode_loop:
-            # the serving fast lane: lower the whole on-device
-            # lax.while_loop decode body (one host transfer per bucket)
-            # instead of a single step — proves the loop-carried cache +
-            # live-mask graph compiles against the production mesh
-            cache_abs = abstract_cache(model, cell, rules, mesh)
-            if decode_loop < 2:
-                raise ValueError("--decode-loop needs >= 2: slot 0 of the "
-                                 "token buffer is the prefill token passed "
-                                 "in, so a 1-token loop lowers a graph "
-                                 "with zero decode steps")
-            from repro.serve import make_decode_loop
-            tok_abs, mn_abs, eos_abs = decode_loop_specs(cell, rules, mesh)
-            loop_fn = make_decode_loop(model, decode_loop, cim)
-            lowered = loop_fn.lower(params_abs, tok_abs, cache_abs,
-                                    mn_abs, eos_abs)
-            # the loop body runs at most max_new - 1 decode steps: slot 0
-            # of the buffer is the prefill token passed IN, not generated
-            # by this graph
-            tokens = cell.global_batch * (decode_loop - 1)
-            meta["decode_loop"] = decode_loop
         else:
             cache_abs = abstract_cache(model, cell, rules, mesh)
             token_abs = decode_token_spec(cell, rules, mesh)
@@ -344,8 +325,8 @@ def main(argv=None):
     p.add_argument("--kv-cache", default="", choices=("", "int8"),
                    help="KV cache storage dtype (int8 = scaled)")
     p.add_argument("--decode-loop", type=int, default=0,
-                   help="decode cells: lower the on-device decode loop "
-                        "with this max-new budget instead of one step")
+                   help="chunk budget (decode steps) of the --continuous "
+                        "round (default 8)")
     p.add_argument("--continuous", type=int, default=0, metavar="SLOTS",
                    help="decode cells: lower one chunked round of the "
                         "continuous-batching slot pool with this many "
@@ -369,6 +350,9 @@ def main(argv=None):
     if args.kv == "paged" and not args.continuous:
         p.error("--kv paged requires --continuous SLOTS (the paged "
                 "pool is a continuous-batching slot-pool layout)")
+    if args.decode_loop and not args.continuous:
+        p.error("--decode-loop is the chunk budget of a --continuous "
+                "SLOTS round")
 
     if args.all:
         fails = sweep(args.out_dir, multi_pod_too=not args.single_pod_only,

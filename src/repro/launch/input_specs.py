@@ -53,15 +53,6 @@ def decode_token_spec(cell: ShapeCell, rules, mesh):
                 rules, mesh)
 
 
-def decode_loop_specs(cell: ShapeCell, rules, mesh):
-    """Inputs of serve.make_decode_loop beyond params/cache: the
-    prefill-sampled token and the per-row max-new/EOS vectors — all (B,)
-    int32, batch-sharded like the decode token."""
-    b = cell.global_batch
-    mk = lambda: _sds((b,), jnp.int32, ("batch",), rules, mesh)
-    return mk(), mk(), mk()
-
-
 def abstract_model_params(model, rules, mesh, packed: str | None = None):
     """Params as ShapeDtypeStructs with shardings.
 

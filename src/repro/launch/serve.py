@@ -2,13 +2,12 @@
 packed) model.  The paper's end-to-end mode: weights stored at 1 byte /
 5-trit weight (base3) or 2 bits/trit (trit2) and dequantized on-load.
 
-Three drivers:
-  * bucket (default) — ServeEngine pops one prompt-length bucket at a
-    time (on-device decode loop per bucket);
-  * ``--continuous`` — the continuous-batching Scheduler: a persistent
-    pool of ``--slots`` decode slots, chunked on-device decode
-    (``--chunk`` steps per host yield) with prefill-into-freed-slot
-    admission;
+Two modes:
+  * default — the continuous-batching scheduler: a persistent pool of
+    ``--slots`` decode slots, chunked on-device decode (``--chunk``
+    steps per host yield) with prefill-into-freed-slot admission, over
+    the paged KV pool (``PagedScheduler``) where the model pages and
+    per-slot dense caches (``Scheduler``) elsewhere (``--kv``);
   * ``--frontend`` — the SLO-aware serving front-end
     (``repro.frontend``): a model registry (``--frontend-models``, one
     scheduler pool per architecture) behind one bounded-queue submit
@@ -19,13 +18,11 @@ Three drivers:
 Request streams: all-at-once (default), a Poisson arrival stream
 (``--arrival-rate`` requests/s), or a recorded JSON trace
 (``--trace-file``: list of {arrival_s, prompt_len, max_new, eos_id}
-plus optional {priority, deadline_s} SLO fields).  With an arrival
-stream all drivers replay the same trace, so their latency percentiles
-are comparable.
+plus optional {priority, deadline_s} SLO fields).
 
   PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
       --smoke --requests 16 --prompt-len 32 --max-new 16 --packed base3 \
-      --continuous --slots 8 --chunk 8 --arrival-rate 50
+      --slots 8 --chunk 8 --arrival-rate 50
 
   PYTHONPATH=src python -m repro.launch.serve --frontend \
       --frontend-models internlm2-1.8b,qwen3-14b --smoke --requests 16 \
@@ -52,7 +49,6 @@ def main(argv=None) -> dict:
     p.add_argument("--requests", type=int, default=8)
     p.add_argument("--prompt-len", type=int, default=32)
     p.add_argument("--max-new", type=int, default=16)
-    p.add_argument("--max-batch", type=int, default=8)
     p.add_argument("--capacity", type=int, default=256)
     p.add_argument("--packed", choices=("base3", "trit2"))
     p.add_argument("--domain", default="float", choices=("float", "int8"),
@@ -71,22 +67,14 @@ def main(argv=None) -> dict:
                    help="decode chunks between restore-scrub repairs "
                         "under --fidelity device (0 disables scrubbing "
                         "— degradation accumulates)")
-    p.add_argument("--legacy-loop", action="store_true",
-                   help="per-step decode driver (one host sync per token) "
-                        "instead of the on-device lax.while_loop")
-    p.add_argument("--continuous", action="store_true",
-                   help="continuous-batching Scheduler (slot pool + "
-                        "chunked decode) instead of the bucket engine")
-    p.add_argument("--slots", type=int, default=0,
-                   help="decode slots for --continuous (default: "
-                        "--max-batch)")
+    p.add_argument("--slots", type=int, default=8,
+                   help="decode slots of the scheduler pool")
     p.add_argument("--chunk", type=int, default=8,
                    help="decode steps per scheduling round (host yield)")
     p.add_argument("--kv", default=None, choices=("dense", "paged"),
-                   help="--continuous/--frontend KV layout: dense "
-                        "per-slot caches or the paged, prefix-shared "
-                        "block pool (default: dense for --continuous, "
-                        "paged for --frontend pools)")
+                   help="KV layout: dense per-slot caches or the "
+                        "paged, prefix-shared block pool (default: "
+                        "paged where the model supports it, else dense)")
     p.add_argument("--page-size", type=int, default=16,
                    help="positions per KV page for --kv paged")
     p.add_argument("--num-pages", type=int, default=0,
@@ -126,43 +114,30 @@ def main(argv=None) -> dict:
                         "within it are shed")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
-    kv = args.kv or ("paged" if args.frontend else "dense")
-    if kv == "paged" and not (args.continuous or args.frontend):
-        p.error("--kv paged requires --continuous or --frontend (the "
-                "paged pool is a slot-pool layout)")
-    if args.frontend and args.continuous:
-        p.error("--frontend drives its registry's scheduler pools "
-                "itself; drop --continuous")
     if args.frontend and (args.packed or args.fidelity == "device"):
         p.error("--frontend pools serve float weights through the "
                 "model registry; packed/device-fidelity serving is the "
-                "bucket/--continuous path")
-    if args.frontend and args.legacy_loop:
-        p.error("--frontend has no legacy per-step loop; its pools are "
-                "chunked schedulers")
+                "scheduler path")
     if args.fidelity == "device" and not args.packed:
         p.error("--fidelity device requires --packed (the device model "
                 "faults packed ternary weights; float serving has no "
                 "device path)")
-    if args.fidelity == "device" and not args.continuous:
-        p.error("--fidelity device requires --continuous (drift + "
-                "restore-scrub are per-chunk hooks of the Scheduler)")
 
     from repro.launch.compile_cache import configure_compile_cache
     configure_compile_cache()
     if args.frontend:
-        return _run_frontend(args, kv)
+        return _run_frontend(args)
 
-    from repro import configs
     from repro.core.cim_linear import CIMConfig, hbm_bytes, ternarize_params
     from repro.models import registry
     from repro.serve import (PagedScheduler, Request, Scheduler,
-                             ServeEngine, latency_stats, load_trace,
-                             make_trace, poisson_arrivals)
+                             latency_stats, load_trace, make_trace,
+                             poisson_arrivals)
 
     t_setup = time.monotonic()
-    cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
+    cfg = _config(args.arch, args.smoke)
     model = registry.build(cfg)
+    kv = args.kv or _default_kv(model)
     params = model.init(jax.random.key(args.seed))
     raw_bytes = hbm_bytes(params)
 
@@ -174,10 +149,10 @@ def main(argv=None) -> dict:
             from repro import faults
             faults.set_fault_model(faults.measured_fault_model(
                 seed=args.seed, drift_rate=0.001))
-        # fail fast for BOTH phases the engines will resolve (a device
+        # fail fast for BOTH phases the scheduler will resolve (a device
         # request splits decode->device / prefill->exact; pinning the
         # decode resolution into the request would poison the prefill
-        # one, so the engines get the unresolved request)
+        # one, so the scheduler gets the unresolved request)
         cim = CIMConfig(mode="ternary", packing=args.packed,
                         domain=args.domain, backend=args.backend,
                         fidelity=args.fidelity)
@@ -190,14 +165,7 @@ def main(argv=None) -> dict:
           + (f", backend={cim_decode.backend}, domain={cim_decode.domain}, "
              f"fidelity={cim_decode.fidelity}" if cim else "") + ")")
 
-    extra = {}
-    if cfg.family == "audio":
-        extra["frames"] = lambda b: jnp.zeros(
-            (b, cfg.encoder_seq, cfg.d_model), cfg.dtype)
-    if cfg.family == "vlm":
-        extra["patches"] = lambda b: jnp.zeros(
-            (b, cfg.encoder_seq, cfg.d_model), cfg.dtype)
-
+    extra = extra_inputs(cfg)
     if args.trace_file:
         trace = load_trace(args.trace_file)
     else:
@@ -205,23 +173,17 @@ def main(argv=None) -> dict:
                                     seed=args.seed)
         trace = make_trace(arrivals, [args.prompt_len], [args.max_new])
 
-    if args.continuous and kv == "paged":
+    if kv == "paged":
         eng = PagedScheduler(model, params, capacity=args.capacity,
-                             slots=args.slots or args.max_batch,
-                             chunk=args.chunk, page_size=args.page_size,
+                             slots=args.slots, chunk=args.chunk,
+                             page_size=args.page_size,
                              num_pages=args.num_pages or None,
                              cim=cim, extra_inputs=extra,
                              scrub_every=args.scrub_every)
-    elif args.continuous:
-        eng = Scheduler(model, params, capacity=args.capacity,
-                        slots=args.slots or args.max_batch,
-                        chunk=args.chunk, cim=cim, extra_inputs=extra,
-                        scrub_every=args.scrub_every)
     else:
-        eng = ServeEngine(model, params, capacity=args.capacity,
-                          max_batch=args.max_batch, cim=cim,
-                          extra_inputs=extra,
-                          on_device_loop=not args.legacy_loop)
+        eng = Scheduler(model, params, capacity=args.capacity,
+                        slots=args.slots, chunk=args.chunk, cim=cim,
+                        extra_inputs=extra, scrub_every=args.scrub_every)
 
     key = jax.random.key(args.seed + 1)
     for i, rec in enumerate(trace):
@@ -233,15 +195,8 @@ def main(argv=None) -> dict:
                            arrival_s=rec["arrival_s"]))
 
     t0 = time.monotonic()
-    setup_s = t0 - t_setup        # weights built and packed, engine ready
-    if args.continuous:
-        done = eng.run()                      # natively arrival-aware
-    else:
-        # run_trace even when every arrival is 0.0 (no sleeps happen):
-        # it stamps latency_s = completion - arrival, the same
-        # definition the Scheduler uses, so the printed p50/p99 are
-        # comparable across drivers
-        done = eng.run_trace()
+    setup_s = t0 - t_setup     # weights built and packed, scheduler ready
+    done = eng.run()
     dt = time.monotonic() - t0
 
     out = {
@@ -260,52 +215,71 @@ def main(argv=None) -> dict:
         sorted((r.uid, r.out_tokens) for r in done)).encode()).hexdigest()
     if cim_decode is not None:
         out["fidelity"] = cim_decode.fidelity
-        # the decode plan request as the engine resolved it
+        # the decode plan request as the scheduler resolved it
         out["plan"] = eng.cim.plan_request()
-    if args.continuous:
-        out.update(decode_loop="continuous", slots=eng.slots,
-                   chunk=eng.chunk, chunks=eng.chunks_run,
-                   slot_occupancy=round(eng.slot_occupancy, 3))
-        if cim_decode is not None and cim_decode.fidelity == "device":
-            out.update(scrubs=eng.scrubs_run,
-                       adc_clip_lo=eng.adc_clip_lo,
-                       adc_clip_hi=eng.adc_clip_hi)
-        if kv == "paged":
-            out.update(kv="paged", page_size=eng.page_size,
-                       num_pages=eng.num_pages,
-                       pages_in_use_peak=eng.allocator.peak_in_use,
-                       kv_bytes_pool=eng.kv_bytes(),
-                       kv_bytes_resident_peak=eng.kv_bytes_resident_peak,
-                       prefix_hit_rate=round(eng.prefix_hit_rate, 3),
-                       attn_cells_computed=eng.attn_cells_computed,
-                       attn_cells_grid=eng.attn_cells_grid,
-                       attn_plan=(eng.attn_plan.describe()
-                                  if eng.attn_plan else None))
-    else:
-        out["decode_loop"] = "legacy" if args.legacy_loop else "device"
+    out.update(kv=kv, slots=eng.slots, chunk=eng.chunk,
+               chunks=eng.chunks_run,
+               slot_occupancy=round(eng.slot_occupancy, 3))
+    if cim_decode is not None and cim_decode.fidelity == "device":
+        out.update(scrubs=eng.scrubs_run, adc_clip_lo=eng.adc_clip_lo,
+                   adc_clip_hi=eng.adc_clip_hi)
+    if kv == "paged":
+        out.update(page_size=eng.page_size, num_pages=eng.num_pages,
+                   pages_in_use_peak=eng.allocator.peak_in_use,
+                   kv_bytes_pool=eng.kv_bytes(),
+                   kv_bytes_resident_peak=eng.kv_bytes_resident_peak,
+                   prefix_hit_rate=round(eng.prefix_hit_rate, 3),
+                   attn_cells_computed=eng.attn_cells_computed,
+                   attn_cells_grid=eng.attn_cells_grid,
+                   attn_plan=(eng.attn_plan.describe()
+                              if eng.attn_plan else None))
     stats = jax.local_devices()[0].memory_stats()
     out["peak_bytes_in_use"] = (stats or {}).get("peak_bytes_in_use")
     print(json.dumps(out))
     return out
 
 
-def _run_frontend(args, kv: str) -> dict:
+def extra_inputs(cfg) -> dict:
+    """Per-family inputs beside the prompt tokens, as ``{name: fn(batch)
+    -> array}``: zero audio frames (encoder-decoder) or image patches
+    (vision-language) for the encoder's ``encoder_seq`` positions."""
+    name = {"audio": "frames", "vlm": "patches"}.get(cfg.family)
+    if name is None:
+        return {}
+    return {name: lambda b: jnp.zeros((b, cfg.encoder_seq, cfg.d_model),
+                                      cfg.dtype)}
+
+
+def _config(arch: str, smoke: bool):
+    from repro import configs
+    return configs.smoke(arch) if smoke else configs.get(arch)
+
+
+def _default_kv(model) -> str:
+    """The paged pool where the model's decode pages, else dense."""
+    return "paged" if model.supports_paged_kv else "dense"
+
+
+def _run_frontend(args) -> dict:
     """The --frontend mode: registry + bounded-queue server + open-loop
     replay, reporting the load-harness stats (goodput, TTFT, latency
     split) plus the registry capacity report."""
     from repro.frontend import (FIFOAdmission, FrontendServer,
                                 ModelRegistry, ModelSpec, SLOAdmission,
                                 replay, trace_requests)
+    from repro.models import registry
     from repro.serve import load_trace, make_trace, poisson_arrivals
 
     names = [m.strip()
              for m in (args.frontend_models or args.arch).split(",")
              if m.strip()]
+    kv = {name: args.kv or _default_kv(registry.build(
+        _config(name, args.smoke))) for name in names}
     reg = ModelRegistry()
     for name in names:
         reg.register(ModelSpec(
-            name=name, arch=name, smoke=args.smoke, kind=kv,
-            capacity=args.capacity, slots=args.slots or args.max_batch,
+            name=name, arch=name, smoke=args.smoke, kind=kv[name],
+            capacity=args.capacity, slots=args.slots,
             chunk=args.chunk, page_size=args.page_size,
             num_pages=args.num_pages or None, seed=args.seed))
 

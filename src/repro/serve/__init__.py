@@ -1,6 +1,6 @@
-from .engine import (ServeEngine, Scheduler, PagedScheduler, Request,
+from .engine import (Scheduler, PagedScheduler, Request,
                      make_prefill_step, make_decode_step,
-                     make_decode_loop, make_chunked_decode_loop,
+                     make_chunked_decode_loop,
                      make_admit_fn, make_paged_decode_loop,
                      make_paged_admit_fn, init_slot_pool, latency_stats,
                      percentile, greedy_sample)  # noqa: F401

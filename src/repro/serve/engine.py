@@ -1,4 +1,4 @@
-"""Batched serving engine over the models' prefill/decode interface.
+"""Continuous-batching serving over the models' prefill/decode interface.
 
 The paper is an inference-accelerator paper, so serving is the primary
 end-to-end driver (examples/serve_cim.py): weights can be served from
@@ -6,45 +6,16 @@ packed-ternary HBM storage (the paper's density claim) by converting
 params with core.cim_linear.ternarize_params — every dense() inside
 prefill/decode then routes through the ternary_matmul kernel.
 
-Engine model: requests are queued, bucketed by prompt length (identical
-lengths batch exactly — no padding approximations in scoring), prefilled
-as a batch, then decoded with per-row EOS/max-token termination.  The
-decode batch keeps running while any row is live; finished rows keep
-decoding into a scratch token that is discarded (standard fixed-batch
-serving).
-
-Two decode drivers:
-  on-device (default) — ``make_decode_loop``: a single jitted
-      ``lax.while_loop`` carries (token, cache, live-mask, token buffer)
-      on device, checks EOS + per-row max-new in-graph, and transfers
-      tokens to the host exactly ONCE per bucket.  The legacy driver
-      blocked on a ``jax.device_get`` after every decode step,
-      serializing host and device.
-  legacy step loop (``on_device_loop=False``) — one jitted step per
-      token with a host-side sync; kept for tests that pin per-step
-      behavior and for debugging.
-
-Both drivers produce identical greedy tokens; ``host_transfers`` counts
-device->host syncs so the one-transfer-per-bucket contract is testable.
-
-``make_decode_step`` is the jitted `serve_step` the multi-pod dry-run
-lowers for the decode_32k / long_500k cells.
-
-Continuous batching (``Scheduler``): the bucket engine drains one static
-batch at a time, so decode slots sit empty while long requests finish
-and new arrivals queue behind the whole bucket.  The Scheduler instead
-keeps a persistent pool of ``slots`` decode lanes whose on-device state
-(KV/carry, live-mask, per-slot max-new/EOS budgets) survives across
-scheduling rounds:
+``Scheduler`` keeps a persistent pool of ``slots`` decode lanes whose
+on-device state (KV/carry, live-mask, per-slot max-new/EOS budgets)
+survives across scheduling rounds:
 
   * each slot carries an independent batch-1 decode state stacked on a
     leading slot axis; ``make_chunked_decode_loop`` advances every slot
     with a vmapped single-row decode, so slots at DIFFERENT sequence
-    positions coexist in one jitted ``lax.while_loop`` (the batched
-    drivers share one scalar cache position and cannot do this);
+    positions coexist in one jitted ``lax.while_loop``;
   * the loop runs up to ``chunk`` decode steps, then yields to the host
-    for admission with ONE device->host transfer (the PR 2 invariant,
-    now per chunk instead of per bucket);
+    for admission with ONE device->host transfer per chunk;
   * admission prefills newly arrived requests and scatters their state
     into freed slots in-graph (``make_admit_fn``) — compaction is the
     overwrite, no pool reshape, no extra transfer (the prefill token
@@ -52,9 +23,15 @@ scheduling rounds:
   * finished rows are retired host-side from the per-chunk transfer and
     their slots returned to the free list.
 
-Per-request tokens are bitwise identical to both PR 2 drivers (pinned in
-tests/test_continuous.py): a slot's computation is exactly the batch-1
-decode of that request, and greedy tokens are batch-shape independent.
+``PagedScheduler`` runs the same rounds over a paged, prefix-shared KV
+block pool (models/paged_kv.py) instead of per-slot dense caches.
+
+Per-request tokens are bitwise identical to a plain batch-1
+``make_prefill_step`` + ``make_decode_step`` loop (pinned in
+tests/test_serve.py for every architecture): a slot's computation is
+exactly the batch-1 decode of that request.  ``make_decode_step`` is
+also the jitted ``serve_step`` the jaxpr audit (serve/manifest.py)
+traces.
 """
 from __future__ import annotations
 
@@ -95,53 +72,6 @@ def make_decode_step(model, cim=None) -> Callable:
     return jax.jit(decode_step, donate_argnums=(2,))
 
 
-def make_decode_loop(model, max_new: int, cim=None) -> Callable:
-    """Jitted whole-bucket decode: ``lax.while_loop`` over decode steps
-    with the live-mask, per-row budgets and the token buffer all carried
-    on device.
-
-    fn(params, tok0, state, max_new_row, eos_row) ->
-        (buf (B, max_new) int32, counts (B,) int32, steps () int32)
-
-    tok0 is the prefill-sampled token (recorded at buf[:, 0], exactly
-    like the legacy driver records it before its first decode step);
-    counts[b] is how many of row b's buffer slots are real output
-    (min(EOS position + 1, max_new_row[b])); steps is the number of
-    decode steps executed (for steps_run accounting).  Rows append in
-    lockstep while live, so a row's tokens always occupy buf[b, :counts].
-    """
-    def decode_loop(params, tok, state, max_new_row, eos_row):
-        b = tok.shape[0]
-        buf = jnp.zeros((b, max_new), jnp.int32).at[:, 0].set(tok)
-        counts = jnp.ones((b,), jnp.int32)
-        live = (counts < max_new_row) & (tok != eos_row)
-
-        def cond(carry):
-            step, tok, state, live, buf, counts = carry
-            return jnp.any(live) & (step < max_new - 1)
-
-        def body(carry):
-            step, tok, state, live, buf, counts = carry
-            logits, state = model.decode(params, tok[:, None], state,
-                                         cim=cim)
-            tok = greedy_sample(logits)
-            buf = buf.at[:, step + 1].set(
-                jnp.where(live, tok, buf[:, step + 1]))
-            counts = counts + live.astype(jnp.int32)
-            live = live & (counts < max_new_row) & (tok != eos_row)
-            return step + 1, tok, state, live, buf, counts
-
-        steps, _, _, _, buf, counts = jax.lax.while_loop(
-            cond, body, (jnp.zeros((), jnp.int32), tok, state, live, buf,
-                         counts))
-        return buf, counts, steps
-
-    # no donate_argnums: the while_loop carries the cache internally and
-    # XLA cannot alias the donated input into the loop state (it would
-    # only warn on every bucket).
-    return jax.jit(decode_loop)
-
-
 # =====================================================================
 # continuous batching: slot pool + chunked decode loop
 # =====================================================================
@@ -155,9 +85,8 @@ def init_slot_pool(model, slots: int, capacity: int):
 
 
 def make_chunked_decode_loop(model, chunk: int, cim=None, spmd_axes=None):
-    """Chunked variant of ``make_decode_loop`` over a slot pool: run up
-    to ``chunk`` decode steps in one jitted ``lax.while_loop``, then
-    yield to the host for admission.
+    """Decode a slot pool: run up to ``chunk`` decode steps in one
+    jitted ``lax.while_loop``, then yield to the host for admission.
 
     fn(params, tok (P,), state_pool, live (P,), made (P,), fresh (P,),
        max_new_row (P,), eos_row (P,)) ->
@@ -171,14 +100,13 @@ def make_chunked_decode_loop(model, chunk: int, cim=None, spmd_axes=None):
     constraints inside the model shard the pool over data parallelism
     (see dist.sharding.slot_spmd_axes).
 
-    Semantics per slot are exactly ``make_decode_loop``'s per row:
-    freshly admitted slots emit their prefill-sampled token at buf[:, 0]
-    (already counted in ``made`` by the admit scatter), live rows append
-    in per-row order at buf[row, cnt[row]], ``made`` tracks the per-slot
-    budget and EOS flips ``live`` in-graph.  ``steps`` is the number of
-    decode steps executed, ``occ`` the live-slot-steps (occupancy
-    accounting); dead/empty slots keep decoding into scratch state, like
-    finished rows in the fixed-batch drivers.
+    Per slot: freshly admitted slots emit their prefill-sampled token at
+    buf[:, 0] (already counted in ``made`` by the admit scatter), live
+    rows append in per-row order at buf[row, cnt[row]], ``made`` tracks
+    the per-slot budget and EOS flips ``live`` in-graph.  ``steps`` is
+    the number of decode steps executed, ``occ`` the live-slot-steps
+    (occupancy accounting); dead/empty slots keep decoding into scratch
+    state.
     """
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")
@@ -220,7 +148,7 @@ def make_chunked_decode_loop(model, chunk: int, cim=None, spmd_axes=None):
         return tok, state, live, made, buf, cnt, steps, occ
 
     # no donation: the while_loop carries the pool state internally, so
-    # XLA cannot alias a donated input into it (same as make_decode_loop)
+    # XLA cannot alias a donated input into it
     return jax.jit(chunk_step)
 
 
@@ -237,7 +165,7 @@ def make_admit_fn() -> Callable:
             state, new_state)
         t0 = tok0[0]
         tok = tok.at[slot].set(t0)
-        # same initial-liveness rule as the bucket loop: tok0 is token 1
+        # tok0 is token 1 of the request
         made = made.at[slot].set(1)
         live = live.at[slot].set((1 < max_new) & (t0 != eos_id))
         fresh = fresh.at[slot].set(True)
@@ -418,9 +346,7 @@ def percentile(vals: list, q: float) -> float:
     """Linear interpolation between order statistics (numpy's default
     method).  The previous nearest-rank rounding (``int(q*(n-1)+0.5)``)
     made small-sample p99 degenerate to the sample max — for n <= 50
-    every q > ~0.5 + 1/(2(n-1)) picked the last element — which biased
-    the continuous-vs-bucket p99 bench gate toward whichever driver's
-    single worst request was smaller."""
+    every q > ~0.5 + 1/(2(n-1)) picked the last element."""
     return float(np.percentile(vals, 100.0 * q))
 
 
@@ -454,209 +380,7 @@ def latency_stats(reqs: list) -> dict:
             "service_p99_s": round(percentile(service, 0.99), 4)}
 
 
-class _EngineBase:
-    """Request bookkeeping shared by the bucket and continuous engines:
-    the queue, the completion list, and the host-transfer counter that
-    both transfer contracts (one per bucket / one per chunk) are tested
-    through."""
-
-    def __init__(self, model, params, capacity: int, cim, extra_inputs):
-        self.model = model
-        self.params = params
-        self.capacity = capacity
-        # resolve the plan request ONCE at engine construction: 'auto'
-        # backend/interpret pin against the kernel registry here, so an
-        # incapable backend fails loudly now instead of mid-decode, and
-        # every dense() under this engine hits the plan cache with a
-        # fully concrete request.  Resolution is PER PHASE (noise-aware
-        # routing): a `fidelity='device'` request runs the fault-
-        # injected path only for decode — prefill routes back to an
-        # exact backend (a prefill upset corrupts the whole KV prefix;
-        # a decode upset perturbs one sampled token).  For exact
-        # requests both resolutions are identical, so the exact serving
-        # path is bitwise-unchanged.
-        if cim is not None:
-            self.cim = cim.resolve()
-            self.cim_prefill = cim.resolve(phase="prefill")
-        else:
-            self.cim = self.cim_prefill = None
-        self.extra_inputs = extra_inputs or {}
-        self._prefill = make_prefill_step(model, capacity, self.cim_prefill)
-        self.queue: list[Request] = []
-        self.completed: list[Request] = []
-        self.steps_run = 0
-        self.host_transfers = 0
-
-    def submit(self, req: Request):
-        self.queue.append(req)
-
-    def _device_get(self, x):
-        """All device->host syncs route through here (transfer
-        counting)."""
-        self.host_transfers += 1
-        return jax.device_get(x)
-
-    @property
-    def generated_tokens(self) -> int:
-        return sum(len(r.out_tokens) for r in self.completed)
-
-    def _arrival_pump(self, clock, sleep, try_admit, busy, serve_round):
-        """Shared arrival loop for trace serving — the ONE place whose
-        clock semantics both drivers inherit (the serve_continuous
-        bench compares their latencies, so they must not drift):
-        FIFO-sort the queue by (arrival_s, uid), offer arrived requests
-        to `try_admit(req, now)` (return False to defer — e.g. no free
-        slot; on success the admitter stamps `admit_s` so latency_stats
-        can split queue wait from service time), sleep to the next
-        arrival when nothing is `busy`, otherwise run one
-        `serve_round(elapsed)`.  `serve_round` stamps `latency_s` as
-        elapsed() - arrival_s (queue wait included)."""
-        pending = sorted(self.queue, key=lambda r: (r.arrival_s, r.uid))
-        self.queue = []
-        t0 = clock()
-        elapsed = lambda: clock() - t0
-        while pending or busy():
-            now = elapsed()
-            while pending and pending[0].arrival_s <= now:
-                if not try_admit(pending[0], now):
-                    break
-                pending.pop(0)
-            if not busy():
-                delay = pending[0].arrival_s - elapsed()
-                if delay > 0:
-                    sleep(delay)
-                continue
-            serve_round(elapsed)
-        return self.completed
-
-
-class ServeEngine(_EngineBase):
-    def __init__(self, model, params, capacity: int = 512,
-                 max_batch: int = 8, cim=None, extra_inputs=None,
-                 on_device_loop: bool = True):
-        super().__init__(model, params, capacity, cim, extra_inputs)
-        self.max_batch = max_batch
-        self.on_device_loop = on_device_loop
-        self._decode = make_decode_step(model, self.cim)
-        self._loops: dict[int, Callable] = {}   # max_new cap -> jitted loop
-
-    def _next_bucket(self) -> list[Request]:
-        """Pop up to max_batch queued requests sharing one prompt length
-        (single pass: partition the queue instead of list.remove per hit)."""
-        if not self.queue:
-            return []
-        length = len(self.queue[0].prompt)
-        batch, rest = [], []
-        for r in self.queue:
-            if len(batch) < self.max_batch and len(r.prompt) == length:
-                batch.append(r)
-            else:
-                rest.append(r)
-        self.queue = rest
-        return batch
-
-    def _batch_inputs(self, reqs: list[Request]) -> dict:
-        return _batch_inputs(reqs, self.extra_inputs)
-
-    def _decode_loop_for(self, max_new: int) -> Callable:
-        # bucket the static loop width up to a power of two: max_new is
-        # request-controlled, and compiling (and retaining) one jitted
-        # while_loop per distinct value would grow without bound.  The
-        # live-mask still exits at the true per-row budgets; only the
-        # token buffer is wider.
-        cap = 1 << max(max_new - 1, 0).bit_length()
-        if cap not in self._loops:
-            self._loops[cap] = make_decode_loop(self.model, cap, self.cim)
-        return self._loops[cap]
-
-    # ------------------------------------------------------------------
-    def _run_bucket_device(self, reqs: list[Request]):
-        """Fast lane: prefill, then one on-device decode loop and ONE
-        host transfer for the whole bucket."""
-        tok, state = self._prefill(self.params, self._batch_inputs(reqs))
-        self.steps_run += 1
-        max_new = max(r.max_new for r in reqs)
-        loop = self._decode_loop_for(max_new)
-        max_new_row = jnp.asarray([r.max_new for r in reqs], jnp.int32)
-        eos_row = jnp.asarray([r.eos_id for r in reqs], jnp.int32)
-        buf, counts, steps = loop(self.params, tok, state, max_new_row,
-                                  eos_row)
-        buf, counts, steps = self._device_get((buf, counts, steps))
-        self.steps_run += int(steps)
-        for r, row, cnt in zip(reqs, buf, counts):
-            r.out_tokens.extend(int(t) for t in row[: int(cnt)])
-
-    def _run_bucket_legacy(self, reqs: list[Request]):
-        """Original step-by-step driver: one host sync per decode step."""
-        tok, state = self._prefill(self.params, self._batch_inputs(reqs))
-        self.steps_run += 1
-        live = [True] * len(reqs)
-        for i, (r, t) in enumerate(zip(reqs, self._device_get(tok))):
-            r.out_tokens.append(int(t))
-            if len(r.out_tokens) >= r.max_new or int(t) == r.eos_id:
-                live[i] = False
-        max_new = max(r.max_new for r in reqs)
-        for _ in range(max_new - 1):
-            if not any(live):
-                break
-            tok, state = self._decode(self.params, tok, state)
-            self.steps_run += 1
-            for i, (r, t) in enumerate(zip(reqs, self._device_get(tok))):
-                if not live[i]:
-                    continue
-                r.out_tokens.append(int(t))
-                if len(r.out_tokens) >= r.max_new or int(t) == r.eos_id:
-                    live[i] = False
-
-    def run(self) -> list[Request]:
-        """Serve the whole queue; returns completed requests."""
-        run_bucket = (self._run_bucket_device if self.on_device_loop
-                      else self._run_bucket_legacy)
-        while self.queue:
-            reqs = self._next_bucket()
-            t0 = time.monotonic()
-            run_bucket(reqs)
-            dt = time.monotonic() - t0
-            for r in reqs:
-                r.done = True
-                r.latency_s = dt
-                self.completed.append(r)
-        return self.completed
-
-    def run_trace(self, clock=time.monotonic, sleep=time.sleep
-                  ) -> list[Request]:
-        """Replay arrival-stamped requests through the bucket driver
-        (the shared ``_arrival_pump``): a request becomes visible at
-        its ``arrival_s``; each round serves ONE bucket of whatever has
-        arrived, so new arrivals can only be admitted at bucket
-        boundaries — the baseline the continuous Scheduler is
-        benchmarked against."""
-        run_bucket = (self._run_bucket_device if self.on_device_loop
-                      else self._run_bucket_legacy)
-
-        def admit(req, now):
-            self.queue.append(req)
-            return True
-
-        def serve_round(elapsed):
-            reqs = self._next_bucket()
-            # the bucket driver's real admission is the bucket pop —
-            # a request "waits" until its bucket starts serving
-            admit_t = elapsed()
-            for r in reqs:
-                r.admit_s = admit_t
-            run_bucket(reqs)
-            done_t = elapsed()
-            for r in reqs:
-                r.done = True
-                r.latency_s = done_t - r.arrival_s
-                self.completed.append(r)
-
-        return self._arrival_pump(clock, sleep, admit,
-                                  lambda: bool(self.queue), serve_round)
-
-
-class Scheduler(_EngineBase):
+class Scheduler:
     """Continuous-batching serve scheduler over a persistent slot pool.
 
     ``slots`` decode lanes live on device across scheduling rounds; each
@@ -683,7 +407,31 @@ class Scheduler(_EngineBase):
                  sleep=time.sleep, scrub_every: Optional[int] = 8):
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
-        super().__init__(model, params, capacity, cim, extra_inputs)
+        self.model = model
+        self.params = params
+        self.capacity = capacity
+        # resolve the plan request ONCE at construction: 'auto'
+        # backend/interpret pin against the kernel registry here, so an
+        # incapable backend fails loudly now instead of mid-decode, and
+        # every dense() under this scheduler hits the plan cache with a
+        # fully concrete request.  Resolution is PER PHASE (noise-aware
+        # routing): a `fidelity='device'` request runs the fault-
+        # injected path only for decode — prefill routes back to an
+        # exact backend (a prefill upset corrupts the whole KV prefix;
+        # a decode upset perturbs one sampled token).  For exact
+        # requests both resolutions are identical, so the exact serving
+        # path is bitwise-unchanged.
+        if cim is not None:
+            self.cim = cim.resolve()
+            self.cim_prefill = cim.resolve(phase="prefill")
+        else:
+            self.cim = self.cim_prefill = None
+        self.extra_inputs = extra_inputs or {}
+        self._prefill = make_prefill_step(model, capacity, self.cim_prefill)
+        self.queue: list[Request] = []
+        self.completed: list[Request] = []
+        self.steps_run = 0
+        self.host_transfers = 0
         self.slots = slots
         self.chunk = chunk
         self._clock = clock
@@ -904,15 +652,45 @@ class Scheduler(_EngineBase):
         clock, used to stamp completion latencies."""
         self._serve_round(elapsed)
 
+    def submit(self, req: Request):
+        self.queue.append(req)
+
     def run(self) -> list[Request]:
-        """Serve the whole queue continuously (the shared
-        ``_arrival_pump``); returns completed requests."""
-        # oldest arrived request into the first free slot, FIFO; defer
-        # admission (False) when the pool is full — or, paged, when the
-        # page pool cannot cover the request yet
-        return self._arrival_pump(self._clock, self._sleep,
-                                  self.try_admit, self.is_busy,
-                                  self._serve_round)
+        """Serve the whole queue; returns completed requests.
+
+        The arrival loop: FIFO-sort the queue by (arrival_s, uid), offer
+        every arrived request to ``try_admit`` until one is deferred
+        (pool full — or, paged, pages not coverable yet), sleep to the
+        next arrival when no slot is busy, otherwise run one round.
+        ``latency_s`` is stamped as completion - arrival_s (queue wait
+        included)."""
+        pending = sorted(self.queue, key=lambda r: (r.arrival_s, r.uid))
+        self.queue = []
+        t0 = self._clock()
+        elapsed = lambda: self._clock() - t0
+        while pending or self.is_busy():
+            now = elapsed()
+            while pending and pending[0].arrival_s <= now:
+                if not self.try_admit(pending[0], now):
+                    break
+                pending.pop(0)
+            if not self.is_busy():
+                delay = pending[0].arrival_s - elapsed()
+                if delay > 0:
+                    self._sleep(delay)
+                continue
+            self._serve_round(elapsed)
+        return self.completed
+
+    def _device_get(self, x):
+        """All device->host syncs route through here (transfer
+        counting)."""
+        self.host_transfers += 1
+        return jax.device_get(x)
+
+    @property
+    def generated_tokens(self) -> int:
+        return sum(len(r.out_tokens) for r in self.completed)
 
     @property
     def slot_occupancy(self) -> float:

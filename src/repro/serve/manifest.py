@@ -91,13 +91,6 @@ def _decode_step(model):
                 _cache(model, B, CAP))
 
 
-def _decode_loop(model):
-    from .engine import make_decode_loop
-    fn = make_decode_loop(model, max_new=CHUNK + 1)
-    row = _sds((B,), jnp.int32)
-    return fn, (_params(model), row, _cache(model, B, CAP), row, row)
-
-
 def _chunked_loop(model):
     from .engine import make_chunked_decode_loop
     fn = make_chunked_decode_loop(model, CHUNK)
@@ -140,17 +133,14 @@ def entries() -> tuple[AuditedEntry, ...]:
     return (
         AuditedEntry("serve.prefill_step", _prefill, (), 2,
                      "batched prefill; nothing donated (params are "
-                     "reused across buckets)"),
+                     "reused across admissions)"),
         AuditedEntry("serve.decode_step", _decode_step, (2,), 2,
-                     "legacy per-token step; the cache is donated and "
+                     "plain per-token step; the cache is donated and "
                      "must alias (no 2x cache memory)"),
-        AuditedEntry("serve.decode_loop", _decode_loop, (), 3,
-                     "on-device bucket loop; deliberately NO donation "
-                     "— the while_loop carries the cache internally "
-                     "and XLA cannot alias into loop state"),
         AuditedEntry("serve.chunked_decode_loop", _chunked_loop, (), 8,
-                     "continuous-batching chunk; no donation (same "
-                     "while_loop reason)"),
+                     "continuous-batching chunk; deliberately NO "
+                     "donation — the while_loop carries the pool "
+                     "internally and XLA cannot alias into loop state"),
         AuditedEntry("serve.admit", _admit, (0, 1, 2, 3, 4, 5, 6), 7,
                      "admission scatter: pool + every control lane "
                      "donated and aliased in place"),

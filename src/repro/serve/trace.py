@@ -1,9 +1,8 @@
-"""Arrival traces for the serving engines.
+"""Arrival traces for the serving schedulers.
 
 A trace is a list of per-request dicts ``{"arrival_s", "prompt_len",
-"max_new", "eos_id", "priority", "deadline_s"}`` — what the drivers
-consume: the bucket engine via ``ServeEngine.run_trace``, the
-continuous ``Scheduler`` natively, and the front-end load generator
+"max_new", "eos_id", "priority", "deadline_s"}`` — what the schedulers
+consume natively, and the front-end load generator
 (``repro.frontend.loadgen``) through its open-loop replay.  The last
 two fields encode SLO classes for the front-end's admission policies
 (``priority``: lower is more urgent, default 0; ``deadline_s``: a
@@ -47,9 +46,8 @@ def poisson_arrivals(n: int, rate_per_s: float, seed: int = 0
 
 def bursty_arrivals(n: int, bursts: int = 2, gap_s: float = 0.25,
                     spread_s: float = 0.02, seed: int = 0) -> list[float]:
-    """n arrivals in `bursts` tight clusters `gap_s` apart — the adverse
-    pattern for bucket-at-a-time serving: a whole burst queues behind
-    the bucket currently draining."""
+    """n arrivals in `bursts` tight clusters `gap_s` apart — a burst
+    larger than the slot pool queues for admission."""
     rng = random.Random(seed)
     out = []
     per = -(-n // bursts)

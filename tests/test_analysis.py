@@ -654,7 +654,14 @@ def test_cli_jaxpr_injection_exits_nonzero():
 
 def test_manifest_entries_declare_unique_names():
     names = [e.name for e in jaxpr_audit.manifest_entries()]
-    assert len(names) == len(set(names)) and len(names) >= 9
+    assert len(names) == len(set(names))
+    # every jitted entry the serving, training and front-end paths
+    # dispatch is audited
+    assert set(names) >= {
+        "serve.prefill_step", "serve.decode_step",
+        "serve.chunked_decode_loop", "serve.admit",
+        "serve.paged_decode_loop", "serve.paged_admit",
+        "train.train_step", "frontend.pump"}
 
 
 # ------------------------------------------------- dead suppressions

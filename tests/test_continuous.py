@@ -1,7 +1,7 @@
-"""Continuous-batching scheduler (ISSUE 3 acceptance):
+"""Continuous-batching scheduler:
 
-  * chunked-loop tokens are bitwise identical per request to BOTH PR 2
-    drivers (on-device bucket loop and legacy per-step loop);
+  * chunked-loop tokens are bitwise identical per request to the plain
+    batch-1 prefill/decode loop (tests/serve_reference.py);
   * slot-state isolation: a slot reclaimed by compaction (admit-scatter
     over a freed slot) reproduces the solo run of the new request
     exactly, with no bleed-through from the previous occupant;
@@ -25,8 +25,9 @@ import pytest
 
 from repro import configs
 from repro.models import registry
-from repro.serve import (Request, Scheduler, ServeEngine, bursty_arrivals,
-                         make_trace, poisson_arrivals, load_trace)
+from repro.serve import (Request, Scheduler, bursty_arrivals, make_trace,
+                         poisson_arrivals, load_trace)
+from serve_reference import reference_tokens
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -56,44 +57,29 @@ def _requests(cfg, specs):
 # ------------------------------------------------- token parity
 
 def test_chunked_tokens_match_both_pr2_drivers():
-    """Same requests through Scheduler, device bucket loop, and legacy
-    step loop: per-request token VALUES must agree bitwise."""
+    """Same requests through the Scheduler and the plain batch-1
+    prefill/decode loop: per-request token VALUES must agree bitwise."""
     cfg, model, params = _setup()
     specs = [(i, 8, 3 + 2 * i) for i in range(4)]
 
-    outs = []
-    for engine in (
-        Scheduler(model, params, capacity=64, slots=4, chunk=3),
-        ServeEngine(model, params, capacity=64, max_batch=4,
-                    on_device_loop=True),
-        ServeEngine(model, params, capacity=64, max_batch=4,
-                    on_device_loop=False),
-    ):
-        for r in _requests(cfg, specs):
-            engine.submit(r)
-        outs.append({r.uid: list(r.out_tokens) for r in engine.run()})
-    assert outs[0] == outs[1] == outs[2]
-    assert all(len(outs[0][uid]) == mn for uid, _, mn in specs)
+    sch = Scheduler(model, params, capacity=64, slots=4, chunk=3)
+    for r in _requests(cfg, specs):
+        sch.submit(r)
+    got = {r.uid: list(r.out_tokens) for r in sch.run()}
+    assert got == reference_tokens(model, params, _requests(cfg, specs), 64)
+    assert all(len(got[uid]) == mn for uid, _, mn in specs)
 
 
 def test_mixed_prompt_lengths_one_pool():
     """Slots at different sequence positions coexist: mixed prompt
-    lengths decode concurrently in one pool (the bucket driver would
-    split them into separate batches)."""
+    lengths decode concurrently in one pool."""
     cfg, model, params = _setup()
     specs = [(0, 4, 5), (1, 8, 5), (2, 16, 5), (3, 6, 5)]
     sch = Scheduler(model, params, capacity=64, slots=4, chunk=4)
     for r in _requests(cfg, specs):
         sch.submit(r)
     got = {r.uid: list(r.out_tokens) for r in sch.run()}
-
-    ref = {}
-    for spec in specs:
-        eng = ServeEngine(model, params, capacity=64, max_batch=1)
-        for r in _requests(cfg, [spec]):
-            eng.submit(r)
-        ref.update({r.uid: list(r.out_tokens) for r in eng.run()})
-    assert got == ref
+    assert got == reference_tokens(model, params, _requests(cfg, specs), 64)
 
 
 # ------------------------------------------------- compaction / isolation
@@ -110,13 +96,9 @@ def test_slot_reuse_isolation_after_compaction():
     got = {r.uid: list(r.out_tokens) for r in sch.run()}
     assert sorted(got) == [s[0] for s in specs]
 
-    for spec in specs:
-        eng = ServeEngine(model, params, capacity=64, max_batch=1)
-        for r in _requests(cfg, [spec]):
-            eng.submit(r)
-        solo = eng.run()[0]
-        assert got[solo.uid] == list(solo.out_tokens), \
-            f"slot reuse corrupted request {solo.uid}"
+    solo = reference_tokens(model, params, _requests(cfg, specs), 64)
+    for uid, toks in solo.items():
+        assert got[uid] == toks, f"slot reuse corrupted request {uid}"
 
 
 # ------------------------------------------------- starvation / bursts
@@ -273,41 +255,3 @@ def test_sharded_slot_pool_matches_unsharded():
     assert out["devices"] == 8
     assert out["spmd_axes"] == "data"
     assert out["identical"]
-
-
-# ------------------------------------------------- bench contract
-
-def test_serve_continuous_schema_gate():
-    """schema.validate must reject a wallclock payload whose
-    serve_continuous section lost a contract key."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_schema", os.path.join(os.path.dirname(__file__), "..",
-                                     "benchmarks", "schema.py"))
-    schema = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(schema)
-
-    root = os.path.join(os.path.dirname(__file__), "..")
-    payload = json.load(open(os.path.join(root, "BENCH_wallclock.json")))
-    assert schema.validate("wallclock", payload) == []
-
-    broken = dict(payload)
-    broken["serve_continuous"] = {
-        k: v for k, v in payload["serve_continuous"].items()
-        if k != "continuous"}
-    errs = schema.validate("wallclock", broken)
-    assert errs and "serve_continuous" in errs[0]
-
-    broken = dict(payload)
-    broken["serve_continuous"] = dict(
-        payload["serve_continuous"],
-        continuous={k: v for k, v
-                    in payload["serve_continuous"]["continuous"].items()
-                    if k != "slot_occupancy"})
-    errs = schema.validate("wallclock", broken)
-    assert any("slot_occupancy" in e for e in errs)
-
-    missing = dict(payload)
-    del missing["serve_continuous"]
-    errs = schema.validate("wallclock", missing)
-    assert any("serve_continuous" in e for e in errs)

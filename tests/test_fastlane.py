@@ -1,20 +1,17 @@
-"""Decode-path fast lane: shape-adaptive block dispatch, the int8
-MXU domain, the on-device serve loop, and the wall-clock bench metrics.
+"""Decode-path fast lane: shape-adaptive block dispatch and the int8
+MXU domain.
 
-Contracts pinned here (ISSUE 2 acceptance):
+Contracts pinned here:
   * decode shapes (M = 1/4/8) agree pallas == xla == oracle in both
     packing modes, float and int8 domains (int8 bitwise);
   * adaptive blocking cuts padded-M FLOP waste >= 8x vs fixed bm=128
-    for batch <= 16 decode shapes;
-  * the on-device decode loop emits tokens identical to the legacy
-    per-step driver and performs exactly ONE host transfer per bucket.
+    for batch <= 16 decode shapes.
 
 Plus the ExecutionPlan migration contract (ISSUE 4 acceptance): every
 (backend, domain, packing) dispatch cell is bitwise identical between
 the deprecated kwarg routing and plan_matmul/execute, and plan
 resolution under jit is cache-hit free of re-probing.
 """
-import dataclasses
 import warnings
 
 import jax
@@ -25,7 +22,7 @@ import pytest
 from repro.kernels import (execute, ops, plan_cache_clear, plan_cache_info,
                            plan_matmul, ref, shape_of)
 from repro.kernels.ternary_matmul import (DEFAULT_BLOCKS, SUBLANE,
-                                          select_block_shapes,
+                                          _round_up, select_block_shapes,
                                           ternary_matmul_int8)
 
 jax.config.update("jax_platform_name", "cpu")
@@ -226,11 +223,17 @@ class TestPlanDispatchParity:
         assert plan_cache_info().misses == misses + 1   # new shape only
 
 
-# ------------------------------------------------------- bench metrics
+# ------------------------------------------------------- blocking waste
+
+def padded_flops(m: int, k: int, n: int, blocks) -> int:
+    """MAC-FLOPs the grid actually issues: every dim padded up to its
+    block multiple (the kernel zero-pads and the MXU multiplies zeros)."""
+    bm, bn, bk = blocks
+    return 2 * _round_up(m, bm) * _round_up(k, bk) * _round_up(n, bn)
+
 
 class TestWallclockMetrics:
     def test_decode_flop_waste_reduction_ge_8x(self):
-        from benchmarks.wallclock import padded_flops
         for m in (1, 4, 8, 16):
             for mode in ("base3", "trit2"):
                 adaptive = select_block_shapes(m, 1024, 1024, mode)
@@ -238,106 +241,3 @@ class TestWallclockMetrics:
                 red = (padded_flops(m, 1024, 1024, fixed)
                        / padded_flops(m, 1024, 1024, adaptive))
                 assert red >= 8.0, (m, mode, red)
-
-    def test_shape_cell_schema(self):
-        from benchmarks import schema
-        from benchmarks.wallclock import shape_cell
-        cell = shape_cell(8, 1024, 1024, "base3", "decode", "xla",
-                          time_it=False)
-        assert schema.WALLCLOCK_CELL <= cell.keys()
-        assert cell["flop_waste_fixed"] == 16 * cell["flop_waste_adaptive"]
-        assert cell["hbm_bytes_adaptive"] < cell["hbm_bytes_fixed"]
-
-    def test_schema_flags_missing_keys(self):
-        from benchmarks import schema
-        errs = schema.validate("wallclock", {"backend": "xla"})
-        assert errs and "missing top-level keys" in errs[0]
-
-
-# ------------------------------------------------------- serve fast lane
-
-def _setup(arch="internlm2-1.8b"):
-    from repro import configs
-    from repro.models import registry
-    cfg = dataclasses.replace(configs.smoke(arch), dtype=jnp.float32)
-    model = registry.build(cfg)
-    params = model.init(jax.random.key(0))
-    return cfg, model, params
-
-
-def _submit_mixed(eng, cfg, n=6, plen=8):
-    from repro.serve import Request
-    key = jax.random.key(1)
-    for i in range(n):
-        prompt = jax.random.randint(jax.random.fold_in(key, i), (plen,), 0,
-                                    cfg.vocab_size)
-        eng.submit(Request(uid=i, prompt=prompt,
-                           max_new=5 if i % 2 else 3))
-
-
-class TestOnDeviceServeLoop:
-    def test_token_identical_to_legacy(self):
-        from repro.serve import ServeEngine
-        cfg, model, params = _setup()
-        outs = {}
-        for on_device in (True, False):
-            eng = ServeEngine(model, params, capacity=64, max_batch=4,
-                              on_device_loop=on_device)
-            _submit_mixed(eng, cfg)
-            outs[on_device] = {r.uid: r.out_tokens for r in eng.run()}
-        assert outs[True] == outs[False]
-        assert sorted(len(t) for t in outs[True].values()) == [3, 3, 3,
-                                                               5, 5, 5]
-
-    def test_one_host_transfer_per_bucket(self):
-        from repro.serve import ServeEngine
-        cfg, model, params = _setup()
-        eng = ServeEngine(model, params, capacity=64, max_batch=4)
-        _submit_mixed(eng, cfg, n=6)       # 6 reqs, max_batch 4 -> 2 buckets
-        eng.run()
-        assert eng.host_transfers == 2
-        # legacy driver syncs every step: strictly more transfers
-        leg = ServeEngine(model, params, capacity=64, max_batch=4,
-                          on_device_loop=False)
-        _submit_mixed(leg, cfg, n=6)
-        leg.run()
-        assert leg.host_transfers > leg.steps_run / 2
-        assert leg.steps_run == eng.steps_run
-
-    def test_eos_stops_row_on_device(self):
-        from repro.serve import Request, ServeEngine, make_prefill_step
-        cfg, model, params = _setup()
-        prompt = jnp.zeros((4,), jnp.int32)
-        pre = make_prefill_step(model, 32)
-        tok, _ = pre(params, {"tokens": prompt[None]})
-        eng = ServeEngine(model, params, capacity=32, max_batch=1)
-        eng.submit(Request(uid=0, prompt=prompt, max_new=8,
-                           eos_id=int(tok[0])))
-        done = eng.run()
-        assert len(done[0].out_tokens) == 1
-        assert eng.host_transfers == 1
-
-    def test_decode_loop_matches_step_loop_directly(self):
-        from repro.serve import (make_decode_loop, make_decode_step,
-                                 make_prefill_step)
-        cfg, model, params = _setup()
-        prompts = jnp.stack([jnp.arange(6, dtype=jnp.int32),
-                             jnp.arange(6, dtype=jnp.int32)[::-1]])
-        pre = make_prefill_step(model, 32)
-        max_new = 5
-        tok, state = pre(params, {"tokens": prompts})
-        loop = make_decode_loop(model, max_new)
-        buf, counts, steps = loop(
-            params, tok, state,
-            jnp.asarray([max_new, max_new], jnp.int32),
-            jnp.asarray([-1, -1], jnp.int32))
-        assert int(steps) == max_new - 1
-        tok2, state2 = pre(params, {"tokens": prompts})
-        dec = make_decode_step(model)
-        want = [np.asarray(tok2)]
-        for _ in range(max_new - 1):
-            tok2, state2 = dec(params, tok2, state2)
-            want.append(np.asarray(tok2))
-        np.testing.assert_array_equal(np.asarray(buf),
-                                      np.stack(want, axis=1))
-        np.testing.assert_array_equal(np.asarray(counts), [max_new] * 2)

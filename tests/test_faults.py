@@ -13,9 +13,13 @@ Covers the PR's contracts:
   * exact-fidelity serving is untouched by the fault machinery (inert
     hooks, unchanged transfer contract, bitwise-identical tokens);
   * the scrub gate: drift degrades the served weights measurably and
-    the restore-scrub REPAIRS them (bounded by 1 - yield, not a no-op).
+    the restore-scrub REPAIRS them (bounded by 1 - yield, not a no-op);
+  * a classifier served through the device path at the MEASURED TL
+    restore yield keeps its accuracy within ``ACC_DROP_MAX`` of the
+    exact kernels.
 """
 import dataclasses
+import functools
 import json
 import os
 import subprocess
@@ -38,6 +42,8 @@ from repro.kernels import (execute, get_backend, plan_matmul,
                            resolve_backend, route_fidelity, shape_of)
 
 YIELDS = (0.97, 0.995, 0.96)
+# most accuracy the device path may lose against the exact kernels
+ACC_DROP_MAX = 0.05
 
 
 # ------------------------------------------------- confusion channel
@@ -163,6 +169,35 @@ def test_device_execute_deterministic_and_correlated(packing):
     assert bool(jnp.all(jnp.isfinite(y1)))
     corr = jnp.corrcoef(y1.ravel(), exact.ravel())[0, 1]
     assert float(corr) > 0.8                # analog, but the same MAC
+
+    # ... and close enough that a trained classifier served through it
+    # at the measured restore yield keeps its accuracy
+    from benchmarks.common import eval_mlp
+    task, mlp = _classifier()
+
+    def kernel_mm(fidelity):
+        def mm(x, w):
+            pw = kernels.ops.pack_weights(w, packing)
+            return execute(plan_matmul(shape_of(x, pw), packing=packing,
+                                       fidelity=fidelity), x, pw)
+        return mm
+
+    prev = faults.set_fault_model(faults.measured_fault_model(num_mc=1024))
+    try:
+        acc = {f: eval_mlp(mlp, task, matmul=kernel_mm(f), batches=2,
+                           batch=256) for f in ("exact", "device")}
+    finally:
+        faults.set_fault_model(prev)
+    assert acc["device"] >= acc["exact"] - ACC_DROP_MAX, acc
+
+
+@functools.lru_cache(maxsize=None)
+def _classifier():
+    """The paper's small classifier (CIFAR-10 stand-in), trained once."""
+    from benchmarks.common import train_mlp
+    from repro.data import ClassTaskConfig
+    task = ClassTaskConfig(num_classes=10, dim=128, snr=2.5, seed=0)
+    return task, train_mlp(task)
 
 
 # ------------------------------------------------- scrub/drift repair

@@ -72,8 +72,9 @@ def test_int8_kv_scheduler_parity_mixed_lengths():
     """Mixed-length int8-KV pools: the continuous Scheduler (dense slot
     pool, scale buffers allocated up front on the slot axis) and the
     PagedScheduler (scale pages alongside the KV pages) must both be
-    bitwise token-identical to the batch-1 bucket driver."""
-    from repro.serve import PagedScheduler, Request, Scheduler, ServeEngine
+    bitwise token-identical to the plain batch-1 prefill/decode loop."""
+    from repro.serve import PagedScheduler, Request, Scheduler
+    from serve_reference import reference_tokens
     cfg = dataclasses.replace(configs.smoke("internlm2-1.8b"),
                               dtype=jnp.float32, kv_cache_dtype="int8")
     model = registry.build(cfg)
@@ -92,7 +93,7 @@ def test_int8_kv_scheduler_parity_mixed_lengths():
             eng.submit(r)
         return {r.uid: list(r.out_tokens) for r in eng.run()}
 
-    ref = run(ServeEngine(model, params, capacity=32, max_batch=1))
+    ref = reference_tokens(model, params, reqs(), 32)
     dense = Scheduler(model, params, capacity=32, slots=2, chunk=3)
     # the slot pool carries int8 codes + f32 scale lanes from t=0
     assert dense.pool["k"].dtype == jnp.int8

@@ -1,9 +1,9 @@
 """Paged, prefix-shared KV for the slot pool (ISSUE 5 acceptance):
 
   * bitwise token parity: the PagedScheduler emits identical tokens to
-    the dense-pool Scheduler AND both PR 2 bucket drivers (on-device
-    loop, legacy step loop) for mixed prompt lengths, including prompts
-    that do not align to page boundaries;
+    the dense-pool Scheduler AND the plain batch-1 prefill/decode loop
+    (tests/serve_reference.py) for mixed prompt lengths, including
+    prompts that do not align to page boundaries;
   * page reuse isolation: a page freed on EOS/retire and reallocated to
     a later request never leaks stale KV (every request matches its
     solo batch-1 reference);
@@ -19,8 +19,7 @@
     rejected loudly;
   * attend()/flash_attention() accept PagedKV gather-views bitwise;
   * the sharded slot pool (8 fake devices) emits identical tokens
-    (slow subprocess test);
-  * the serve_paged bench schema gate.
+    (slow subprocess test).
 """
 import dataclasses
 import json
@@ -36,7 +35,8 @@ import pytest
 from repro import configs
 from repro.models import registry
 from repro.models import paged_kv
-from repro.serve import (PagedScheduler, Request, Scheduler, ServeEngine)
+from repro.serve import PagedScheduler, Request, Scheduler
+from serve_reference import reference_tokens
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -72,25 +72,20 @@ def _run(engine, reqs):
 # ------------------------------------------------- token parity
 
 def test_paged_tokens_match_all_drivers():
-    """PagedScheduler == dense Scheduler == device bucket loop ==
-    legacy step loop, bitwise, on mixed prompt lengths (page-aligned
-    and not) and mixed budgets."""
+    """PagedScheduler == dense Scheduler == the plain batch-1 loop,
+    bitwise, on mixed prompt lengths (page-aligned and not) and mixed
+    budgets."""
     cfg, model, params = _setup()
     specs = [(0, 8, 5), (1, 12, 3), (2, 6, 7), (3, 16, 4), (4, 9, 1)]
 
-    outs = []
-    for engine in (
-        PagedScheduler(model, params, capacity=32, slots=4, chunk=3,
-                       page_size=4),
-        Scheduler(model, params, capacity=32, slots=4, chunk=3),
-        ServeEngine(model, params, capacity=32, max_batch=1,
-                    on_device_loop=True),
-        ServeEngine(model, params, capacity=32, max_batch=1,
-                    on_device_loop=False),
-    ):
-        outs.append(_run(engine, _requests(cfg, specs)))
-    assert outs[0] == outs[1] == outs[2] == outs[3]
-    assert all(len(outs[0][uid]) == mn for uid, _, mn in specs)
+    paged = _run(PagedScheduler(model, params, capacity=32, slots=4,
+                                chunk=3, page_size=4),
+                 _requests(cfg, specs))
+    dense = _run(Scheduler(model, params, capacity=32, slots=4, chunk=3),
+                 _requests(cfg, specs))
+    ref = reference_tokens(model, params, _requests(cfg, specs), 32)
+    assert paged == dense == ref
+    assert all(len(paged[uid]) == mn for uid, _, mn in specs)
 
 
 def test_admission_prefill_state_holds_the_prompt_pages():
@@ -137,11 +132,9 @@ def test_recycled_pages_never_leak_stale_kv():
     assert sch.pages_in_use == 0            # every page returned
     assert sch.allocator.peak_in_use > 0
 
-    for spec in specs:
-        eng = ServeEngine(model, params, capacity=32, max_batch=1)
-        solo = _run(eng, _requests(cfg, [spec]))
-        assert got[spec[0]] == solo[spec[0]], \
-            f"recycled page corrupted request {spec[0]}"
+    solo = reference_tokens(model, params, _requests(cfg, specs), 32)
+    for uid, toks in solo.items():
+        assert got[uid] == toks, f"recycled page corrupted request {uid}"
 
 
 def test_eos_frees_pages_for_reuse():
@@ -205,7 +198,9 @@ def test_request_exceeding_whole_pool_fails_loudly():
 def test_prefix_sharing_matches_private_copies():
     """Shared read-only pages produce the same tokens as private
     copies (share_prefix=False) and as the dense pool — and actually
-    fire on identical and cross-length prefixes."""
+    fire on identical and cross-length prefixes.  Resident KV is what
+    the pool competes on: the dense pool holds every slot's whole
+    capacity, the paged one at most half of it here."""
     cfg, model, params = _setup()
     base = jax.random.randint(jax.random.key(7), (12,), 0,
                               cfg.vocab_size)
@@ -216,8 +211,8 @@ def test_prefix_sharing_matches_private_copies():
                 Request(uid=3, prompt=jnp.concatenate(
                     [base[:8], base[:4]]), max_new=3)]
 
-    dense = _run(Scheduler(model, params, capacity=32, slots=4, chunk=4),
-                 reqs())
+    dense_pool = Scheduler(model, params, capacity=32, slots=4, chunk=4)
+    dense = _run(dense_pool, reqs())
     shared = PagedScheduler(model, params, capacity=32, slots=4, chunk=4,
                             page_size=4)
     got = _run(shared, reqs())
@@ -231,6 +226,7 @@ def test_prefix_sharing_matches_private_copies():
     assert 0.0 < shared.prefix_hit_rate <= 1.0
     # shared pages cost the pool less than private copies
     assert shared.allocator.peak_in_use < private.allocator.peak_in_use
+    assert dense_pool.kv_bytes() >= 2 * shared.kv_bytes_resident_peak
     # every reference released: the registry is empty again
     assert shared.pages_in_use == 0
 
@@ -674,37 +670,3 @@ def test_paged_attention_reads_its_layer_of_the_whole_pool(layer, lens,
     for i in np.flatnonzero(~live):
         assert (np.asarray(l[i]) == 0).all()
         assert (np.asarray(acc[i]) == 0).all()
-
-
-# ------------------------------------------------- bench contract
-
-def test_serve_paged_schema_gate():
-    """schema.validate must reject a wallclock payload whose
-    serve_paged section lost a contract key."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_schema", os.path.join(os.path.dirname(__file__), "..",
-                                     "benchmarks", "schema.py"))
-    schema = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(schema)
-
-    root = os.path.join(os.path.dirname(__file__), "..")
-    payload = json.load(open(os.path.join(root, "BENCH_wallclock.json")))
-    assert schema.validate("wallclock", payload) == []
-
-    broken = dict(payload)
-    broken["serve_paged"] = {
-        k: v for k, v in payload["serve_paged"].items()
-        if k != "kv_bytes_paged_peak"}
-    errs = schema.validate("wallclock", broken)
-    assert any("kv_bytes_paged_peak" in e for e in errs)
-
-    missing = dict(payload)
-    del missing["serve_paged"]
-    errs = schema.validate("wallclock", missing)
-    assert any("serve_paged" in e for e in errs)
-
-    broken = dict(payload)
-    del broken["claim_paged_kv_bytes_2x"]
-    errs = schema.validate("wallclock", broken)
-    assert any("claim_paged_kv_bytes_2x" in e for e in errs)

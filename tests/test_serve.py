@@ -1,15 +1,23 @@
-"""Serving engine: batched generation, bucketing, packed-ternary serving,
-engine output == manual prefill/decode loop."""
+"""Serving through the schedulers: batched generation, packed-ternary
+serving, tokens == the plain prefill/decode loop for every
+architecture, and the launcher's choice of pool."""
 import dataclasses
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro import configs
 from repro.core.cim_linear import CIMConfig, hbm_bytes, ternarize_params
+from repro.launch.serve import extra_inputs
 from repro.models import registry
-from repro.serve import Request, ServeEngine, make_decode_step, \
-    make_prefill_step
+from repro.serve import PagedScheduler, Request, Scheduler
+from serve_reference import reference_tokens
+
+POOLS = {"dense": Scheduler, "paged": PagedScheduler}
 
 
 def _setup(arch="internlm2-1.8b", dtype=jnp.float32):
@@ -19,67 +27,56 @@ def _setup(arch="internlm2-1.8b", dtype=jnp.float32):
     return cfg, model, params
 
 
-import pytest
+def _serve(sch, reqs) -> dict:
+    for r in reqs:
+        sch.submit(r)
+    return {r.uid: list(r.out_tokens) for r in sch.run()}
 
 
-@pytest.mark.parametrize("on_device_loop", [True, False],
-                         ids=["device-loop", "legacy-step-loop"])
-def test_engine_generates_batch(on_device_loop):
+@pytest.mark.parametrize("kv", ["dense", "paged"])
+def test_engine_generates_batch(kv):
     cfg, model, params = _setup()
-    eng = ServeEngine(model, params, capacity=64, max_batch=4,
-                      on_device_loop=on_device_loop)
+    sch = POOLS[kv](model, params, capacity=64, slots=4, chunk=4)
     key = jax.random.key(1)
     for i in range(6):
         prompt = jax.random.randint(jax.random.fold_in(key, i), (8,), 0,
                                     cfg.vocab_size)
-        eng.submit(Request(uid=i, prompt=prompt, max_new=5))
-    done = eng.run()
+        sch.submit(Request(uid=i, prompt=prompt, max_new=5))
+    done = sch.run()
     assert len(done) == 6
     assert all(len(r.out_tokens) == 5 for r in done)
     assert all(0 <= t < cfg.padded_vocab for r in done for t in r.out_tokens)
+    assert sch.host_transfers == sch.chunks_run
 
 
-@pytest.mark.parametrize("on_device_loop", [True, False],
-                         ids=["device-loop", "legacy-step-loop"])
-def test_engine_matches_manual_loop(on_device_loop):
+@pytest.mark.parametrize("kv", ["dense", "paged"])
+def test_engine_matches_manual_loop(kv):
     cfg, model, params = _setup()
     prompt = jax.random.randint(jax.random.key(2), (8,), 0, cfg.vocab_size)
-
-    eng = ServeEngine(model, params, capacity=64, max_batch=1,
-                      on_device_loop=on_device_loop)
-    eng.submit(Request(uid=0, prompt=prompt, max_new=4))
-    got = eng.run()[0].out_tokens
-
-    pre = make_prefill_step(model, 64)
-    dec = make_decode_step(model)
-    tok, state = pre(params, {"tokens": prompt[None]})
-    want = [int(tok[0])]
-    for _ in range(3):
-        tok, state = dec(params, tok, state)
-        want.append(int(tok[0]))
-    assert got == want
+    reqs = [Request(uid=0, prompt=prompt, max_new=4)]
+    got = _serve(POOLS[kv](model, params, capacity=64, slots=1, chunk=3),
+                 reqs)
+    assert got == reference_tokens(model, params, reqs, 64)
+    assert len(got[0]) == 4
 
 
-def test_bucketing_by_prompt_length():
-    cfg, model, params = _setup()
-    eng = ServeEngine(model, params, capacity=64, max_batch=8)
-    for i, ln in enumerate([8, 8, 16, 8, 16]):
-        eng.submit(Request(uid=i, prompt=jnp.zeros((ln,), jnp.int32),
-                           max_new=2))
-    done = eng.run()
-    assert len(done) == 5
-
-
-def test_eos_stops_row():
-    cfg, model, params = _setup()
-    prompt = jnp.zeros((4,), jnp.int32)
-    eng = ServeEngine(model, params, capacity=32, max_batch=1)
-    # eos = whatever greedy produces first -> generation stops at 1 token
-    pre = make_prefill_step(model, 32)
-    tok, _ = pre(params, {"tokens": prompt[None]})
-    eng.submit(Request(uid=0, prompt=prompt, max_new=8, eos_id=int(tok[0])))
-    done = eng.run()
-    assert len(done[0].out_tokens) == 1
+@pytest.mark.parametrize("arch", configs.ARCHS)
+def test_scheduler_matches_step_reference(arch):
+    """Every architecture keeps a serving path: the scheduler the
+    launcher picks for it (the paged pool where the model pages, the
+    dense pool elsewhere) serves the plain loop's tokens bitwise, with
+    the family's extra inputs as the launcher gives them."""
+    cfg = configs.smoke(arch)
+    model = registry.build(cfg)
+    params = model.init(jax.random.key(0))
+    pool = PagedScheduler if model.supports_paged_kv else Scheduler
+    sch = pool(model, params, capacity=32, slots=2, chunk=4,
+               extra_inputs=extra_inputs(cfg))
+    reqs = [Request(uid=0, max_new=4, prompt=jax.random.randint(
+        jax.random.key(3), (8,), 0, cfg.vocab_size))]
+    got = _serve(sch, reqs)
+    assert got == reference_tokens(model, params, reqs, 32)
+    assert len(got[0]) == 4
 
 
 def test_packed_ternary_serving_runs_and_shrinks_weights():
@@ -88,11 +85,12 @@ def test_packed_ternary_serving_runs_and_shrinks_weights():
     cim = CIMConfig(mode="ternary", packing="base3")
     packed = ternarize_params(params, cim)
     assert hbm_bytes(packed) < raw
-    eng = ServeEngine(model, packed, capacity=32, max_batch=2, cim=cim)
+    sch = PagedScheduler(model, packed, capacity=32, slots=2, chunk=4,
+                         page_size=8, cim=cim)
     for i in range(2):
-        eng.submit(Request(uid=i, prompt=jnp.arange(6, dtype=jnp.int32),
+        sch.submit(Request(uid=i, prompt=jnp.arange(6, dtype=jnp.int32),
                            max_new=3))
-    done = eng.run()
+    done = sch.run()
     assert len(done) == 2
     assert all(len(r.out_tokens) == 3 for r in done)
 
@@ -178,7 +176,7 @@ def test_serve_main_returns_printed_result(capsys, cache_config):
     from repro.launch import serve
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     argv = ["--arch", "internlm2-1.8b", "--smoke", "--packed", "trit2",
-            "--domain", "int8", "--continuous", "--kv", "paged",
+            "--domain", "int8", "--kv", "paged",
             "--requests", "3", "--prompt-len", "8", "--max-new", "5",
             "--slots", "2", "--chunk", "2", "--capacity", "16",
             "--page-size", "4"]
@@ -192,6 +190,36 @@ def test_serve_main_returns_printed_result(capsys, cache_config):
     assert out["host_transfers"] == out["chunks"]
     assert out["attn_plan"] is None      # interpret-only platform: gather
     assert any(cache_config.iterdir())
+
+
+@pytest.mark.parametrize("arch,kv", [("internlm2-1.8b", "paged"),
+                                     ("xlstm-125m", "dense")],
+                         ids=["internlm2-1.8b-paged", "xlstm-125m-dense"])
+def test_launcher_picks_the_pool(arch, kv, cache_config):
+    """Without --kv the launcher serves through the paged pool where the
+    model's decode pages and the dense pool elsewhere, one transfer per
+    chunk either way."""
+    from repro.launch import serve
+    out = serve.main(["--arch", arch, "--smoke", "--requests", "2",
+                      "--prompt-len", "8", "--max-new", "3",
+                      "--slots", "2", "--chunk", "2", "--capacity", "16"])
+    assert out["kv"] == kv
+    assert out["generated_tokens"] == 2 * 3
+    assert out["host_transfers"] == out["chunks"]
+
+
+def test_dryrun_refuses_decode_loop_without_continuous():
+    """--decode-loop is the chunk budget of a --continuous round; alone
+    it names no loop to lower and the dry-run says so (subprocess: the
+    dry-run pins its own device count before jax starts)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.dryrun", "--arch",
+         "internlm2-1.8b", "--shape", "decode_32k", "--decode-loop", "4"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 2, r.stderr[-2000:]
+    assert "--decode-loop is the chunk budget" in r.stderr
 
 
 def test_compile_cache_env_dir_wins(monkeypatch, tmp_path):

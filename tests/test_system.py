@@ -9,7 +9,7 @@ from repro.core.cim_linear import CIMConfig, ternarize_params
 from repro.data import DataConfig, lm_batch
 from repro.models import registry
 from repro.optim import adamw
-from repro.serve import Request, ServeEngine
+from repro.serve import PagedScheduler, Request
 from repro.train import Trainer, TrainerConfig
 
 
@@ -29,7 +29,7 @@ def test_train_then_cim_serve(tmp_path):
     # quantize the trained weights to the paper's 5-trit format and serve
     cim = CIMConfig(mode="ternary", packing="base3")
     packed = ternarize_params(state.params, cim)
-    eng = ServeEngine(model, packed, capacity=96, max_batch=4, cim=cim)
+    eng = PagedScheduler(model, packed, capacity=96, slots=4, cim=cim)
     prompts = lm_batch(data, jnp.asarray(999))["tokens"][:4, :32]
     for i in range(4):
         eng.submit(Request(uid=i, prompt=prompts[i], max_new=4))

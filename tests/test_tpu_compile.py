@@ -133,7 +133,9 @@ def test_fused_decode_reads_the_pool_in_place(spec):
     be placed there and not show in the scratch, so the pool is the
     cell's own size (it is only described, never allocated).  The only
     pool-sized copies left are the chunk's copies of its undonated K
-    and V pool parameters."""
+    and V pool parameters.  And the chunk touches fewer bytes than the
+    same chunk on the ``slot_view`` gather path, which copies each
+    slot's pages out of the pool."""
     import dataclasses
     import re
     from repro import configs
@@ -155,8 +157,9 @@ def test_fused_decode_reads_the_pool_in_place(spec):
     pool = described(jax.eval_shape(
         lambda: paged_kv.init_page_pool(cfg, n_pages, ps)))
     i32, pred = (lambda *d: spec(d, jnp.int32)), (lambda *d: spec(d, bool))
-    compiled = _compile(fn, params, i32(s), pool, i32(s, w), i32(s),
-                        pred(s), i32(s), pred(s), i32(s), i32(s))
+    args = (params, i32(s), pool, i32(s, w), i32(s), pred(s), i32(s),
+            pred(s), i32(s), i32(s))
+    compiled = _compile(fn, *args)
     slab_shape = (n_pages, ps, cfg.num_kv_heads, cfg.hd)
     slab_bytes = 2 * int(np.prod(slab_shape))
     assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes
@@ -174,6 +177,12 @@ def test_fused_decode_reads_the_pool_in_place(spec):
         if out.startswith(whole) and " copy(" in rhs:
             pool_copies += 1
     assert pool_copies <= 2
+
+    def bytes_accessed(c):
+        ca = c.cost_analysis()
+        return (ca[0] if isinstance(ca, list) else ca)["bytes accessed"]
+    gather = make_paged_decode_loop(model, 8).lower(*args).compile()
+    assert bytes_accessed(compiled) < bytes_accessed(gather)
 
 
 # Mellum2's expert matrices (w1/w3 2304 x 896, w2 896 x 2304) over its 64
